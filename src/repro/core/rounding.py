@@ -86,6 +86,16 @@ def _exact_scale(x, n):
     return x * _pow2(n1) * _pow2(n2)
 
 
+def uint_to_f32(x):
+    """Exact float32 of unsigned words below 2**24, through ``int32``.
+
+    Mosaic has no unsigned-to-float conversion, so kernels never cast
+    ``uint32`` to float directly; below 2**24 (every caller's range) the
+    detour is bit-identical to the direct cast."""
+    return lax.bitcast_convert_type(x.astype(jnp.uint32),
+                                    jnp.int32).astype(jnp.float32)
+
+
 def _float_exponent(x):
     """Floor(log2(|x|)) for normal float32; any value < -126 for subnormals.
 
@@ -207,15 +217,15 @@ def _uniform_from_bits(bits, rand_bits: int = 32,
     """
     if randomness == "bittrick":
         mask = jnp.uint32((1 << rand_bits) - 1)
-        comp = ((bits & mask) ^ mask).astype(jnp.float32)
+        comp = uint_to_f32((bits & mask) ^ mask)
         return comp * jnp.float32(2.0 ** -rand_bits)
     if rand_bits == 32:
-        return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+        return uint_to_f32(bits >> jnp.uint32(8)) * jnp.float32(2.0 ** -24)
     if rand_bits not in RAND_BITS_CHOICES:
         raise ValueError(f"rand_bits must be one of {RAND_BITS_CHOICES}, "
                          f"got {rand_bits}")
     mask = jnp.uint32((1 << rand_bits) - 1)
-    low = (bits & mask).astype(jnp.float32)
+    low = uint_to_f32(bits & mask)
     if randomness == "comparison":
         return low * jnp.float32(2.0 ** -rand_bits)
     return (low + jnp.float32(0.5)) * jnp.float32(2.0 ** -rand_bits)

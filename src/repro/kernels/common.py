@@ -35,7 +35,7 @@ from repro.core.grids import Grid, get_grid
 from repro.core.rounding import (RoundingSpec, _ceil_from_decompose,
                                  _exact_scale, _float_exponent,
                                  _uniform_from_bits, get_scheme,
-                                 magnitude_decompose)
+                                 magnitude_decompose, uint_to_f32)
 
 
 def round_block(x, bits, fmt, mode, eps: float, v=None,
@@ -211,7 +211,7 @@ def unpack_block(codes, fmt):
     is_sub = field == 0
     e = jnp.where(is_sub, jnp.int32(fmt.emin),
                   field.astype(jnp.int32) - 1 + fmt.emin)
-    sig = jnp.where(is_sub, m, m + jnp.uint32(1 << mbits)).astype(jnp.float32)
+    sig = uint_to_f32(jnp.where(is_sub, m, m + jnp.uint32(1 << mbits)))
     mag = _exact_scale(sig, e - mbits)
     out = jnp.where(sign == 1, -mag, mag)
     if has_nf:
@@ -278,14 +278,29 @@ def counter_bits_pair(k0, k1, shape, row0=0, col0=0, stream: int = 0):
 
 def _interleaved_words(k0, k1, shape, row0, col0, stream: int):
     """One uint32 word per element of ``shape`` (last two dims = rows,
-    cols; an optional leading batch dim broadcasts through the keys) at
-    HALF the PRF cost: the Threefry counter grid covers column *pairs*
-    ``(row, col // 2)`` keyed by global coordinates, and both output words
-    are consumed (word ``col % 2`` of the pair).  Like every counter
-    derivation here it is partition-invariant and recomputable outside the
-    kernel; ``col0`` may be a traced block offset (dynamic lane
-    alignment)."""
+    cols; an optional leading batch dim broadcasts through the keys).  The
+    Threefry counter is the column *pair* ``(row, col // 2)`` keyed by
+    global coordinates, and column ``col`` takes output word ``col % 2``
+    of its pair.  Like every counter derivation here it is
+    partition-invariant and recomputable outside the kernel; ``col0`` may
+    be a traced block offset (dynamic lane alignment).
+
+    Two bit-identical formulations, chosen by backend: XLA:CPU hashes each
+    pair once and interleaves its two words through a ``(..., pairs, 2)``
+    array; on the TPU that minor dimension of 2 is padded to 128 lanes (a
+    64x blow-up for the gradient-sized draws of the wire codec and the
+    accumulator), so there each pair is hashed at both of its columns and
+    the word picked by parity."""
+    key1 = jnp.uint32(k1) + jnp.uint32(_GOLDEN) * jnp.uint32(stream)
     *lead, rows, cols = shape
+    if jax.default_backend() == "tpu":
+        nd = len(shape)
+        r = (jax.lax.broadcasted_iota(jnp.uint32, shape, nd - 2)
+             + jnp.asarray(row0).astype(jnp.uint32))
+        c = (jax.lax.broadcasted_iota(jnp.uint32, shape, nd - 1)
+             + jnp.asarray(col0).astype(jnp.uint32))
+        x0, x1 = threefry2x32(k0, key1, r, c >> jnp.uint32(1))
+        return jnp.where((c & jnp.uint32(1)) == 0, x0, x1)
     static_col = isinstance(col0, int)
     if static_col:
         off = col0 % 2
@@ -300,8 +315,7 @@ def _interleaved_words(k0, k1, shape, row0, col0, stream: int):
          + jnp.uint32(row0))
     c = (jax.lax.broadcasted_iota(jnp.uint32, wshape, len(lead) + 1)
          + jnp.uint32(cp0))
-    x0, x1 = threefry2x32(
-        k0, jnp.uint32(k1) + jnp.uint32(_GOLDEN) * jnp.uint32(stream), r, c)
+    x0, x1 = threefry2x32(k0, key1, r, c)
     inter = jnp.stack([x0, x1], axis=-1).reshape(
         tuple(lead) + (rows, 2 * n_pairs))
     if static_col:
@@ -406,12 +420,29 @@ def counter_bits_batch(words, shape, rand_bits: int = 32, row0=0, col0=0,
 
 def seed_kernel_prng_words(w0, w1, block_id, *, interpret: bool) -> None:
     """Seed the TPU per-core PRNG from two already-loaded uint32 words
-    (no-op under interpret, where kernel_bits_words re-derives everything
-    from coordinates instead).  The words flavour exists for kernels whose
-    seed operand holds *several* word pairs (batched qmatmul: one pair per
-    batch slice) and must pick one dynamically."""
+    and the block index (no-op under interpret, where kernel_bits_words
+    re-derives everything from coordinates instead).  The words flavour
+    exists for kernels whose seed operand holds *several* word pairs
+    (batched qmatmul: one pair per batch slice) and must pick one
+    dynamically.
+
+    Mosaic's ``prng_seed`` takes at most two words, so the block index is
+    folded into them in uint32 arithmetic: ``w0 ^ block·golden`` and
+    ``w1 + block``.  For a fixed pair the map is injective in the block
+    index (the golden constant is odd), so distinct blocks never share a
+    hardware stream."""
     if not interpret:
-        pltpu.prng_seed(w0, w1, block_id)
+        b = jnp.asarray(block_id).astype(jnp.uint32)
+        w0 = jnp.asarray(w0, jnp.uint32) ^ (b * jnp.uint32(_GOLDEN))
+        pltpu.prng_seed(w0, jnp.asarray(w1, jnp.uint32) + b)
+
+
+def _hw_bits(shape):
+    """Hardware PRNG draw as uint32 (``prng_random_bits`` returns int32,
+    whose arithmetic shifts would corrupt the uint32 mask-and-shift
+    derivations downstream)."""
+    return jax.lax.bitcast_convert_type(pltpu.prng_random_bits(shape),
+                                        jnp.uint32)
 
 
 def kernel_bits_words(w0, w1, shape, row0=0, col0=0, stream: int = 0,
@@ -426,10 +457,10 @@ def kernel_bits_words(w0, w1, shape, row0=0, col0=0, stream: int = 0,
         return counter_bits_reduced(w0, w1, shape, rand_bits, row0=row0,
                                     col0=col0, stream=stream)
     if rand_bits == 32:
-        return pltpu.prng_random_bits(shape)
+        return _hw_bits(shape)
     ratio = 32 // rand_bits
     n_words = (shape[1] + ratio - 1) // ratio
-    words = pltpu.prng_random_bits((shape[0], n_words))
+    words = _hw_bits((shape[0], n_words))
     return _expand_reduced(words, shape, 0, rand_bits)
 
 
@@ -462,7 +493,7 @@ def kernel_bits3(seed_ref, shape, row0, need, *, interpret: bool):
     triple).  The interpret path consumes both Threefry output words per
     call, so three stochastic steps cost two PRF evaluations, not three."""
     if not interpret:
-        return [pltpu.prng_random_bits(shape) if n else None for n in need]
+        return [_hw_bits(shape) if n else None for n in need]
     out = [None, None, None]
     pair, drawn = None, 0
     for i, n in enumerate(need):

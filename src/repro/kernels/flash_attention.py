@@ -57,6 +57,10 @@ SITE_BWD_A, SITE_BWD_B = 1, 2
 
 _DEF_BLOCK = 512
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
+# Scoped-VMEM cap for the flash kernels.  The default 16 MiB is too small
+# for the dk/dv backward at 1024x1024 blocks (its f32 s, p and ds tiles
+# and their transposes); v5e has 128 MiB of VMEM per core.
+_VMEM_LIMIT = 64 * 2 ** 20
 
 
 class AttnSpecs(NamedTuple):
@@ -163,6 +167,30 @@ def _blocks(size, block):
     return b, -(-size // b)
 
 
+def _res_rows(*res):
+    """(B·H, Sq) softmax residuals -> the kernels' (B·H, 1, Sq) layout: a
+    (1, q_block) block of it spans the array's unit middle dim, which
+    Mosaic's (8, 128) block rule accepts where a (1, q_block) block of the
+    (B·H, Sq) array is refused."""
+    return tuple(r.reshape(r.shape[0], 1, r.shape[1]) for r in res)
+
+
+def _kernel_draw(seed_ref, row, block_id, interpret: bool):
+    """The kernels' ``draw`` for one grid block, on row ``row`` of the
+    seeds operand.  On the chip every draw reseeds the hardware PRNG from
+    its own site's words and ``block_id``, so no two sites share bits and
+    a recompute with the same words and block id redraws the same bits.
+    Under interpret the counter derivation keys the element coordinates
+    and ``stream`` instead (the reference's draw)."""
+    def draw(site, shape, row0, col0, stream, rb):
+        w0, w1 = seed_ref[row, 2 * site], seed_ref[row, 2 * site + 1]
+        common.seed_kernel_prng_words(w0, w1, block_id, interpret=interpret)
+        return common.kernel_bits_words(
+            w0, w1, shape, row0=row0, col0=col0, stream=stream,
+            rand_bits=rb, interpret=interpret)
+    return draw
+
+
 def _check_seeds(seeds, n, cols):
     seeds = jnp.asarray(seeds, jnp.uint32)
     if seeds.shape != (n, cols):
@@ -218,7 +246,6 @@ def flash_fwd_p(q, k, v, seeds, specs, *, scale, n_heads: int, n_kv: int,
     qb, n_q = _blocks(Sq, q_block)
     kb, n_k = _blocks(Skv, kv_block)
     q_len = q_offset + Sq
-    any_stoch = any(s.stochastic for s in specs)
 
     def idx_q(bh, i, j, *s):
         return (bh, i, 0)
@@ -227,7 +254,7 @@ def flash_fwd_p(q, k, v, seeds, specs, *, scale, n_heads: int, n_kv: int,
         return (_kv_of(bh, n_heads, n_kv), j, 0)
 
     def idx_ml(bh, i, j, *s):
-        return (bh, i)
+        return (bh, 0, i)
 
     def kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                acc_scr, m_scr, l_scr):
@@ -239,16 +266,7 @@ def flash_fwd_p(q, k, v, seeds, specs, *, scale, n_heads: int, n_kv: int,
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        if any_stoch:
-            common.seed_kernel_prng_words(
-                seed_ref[bh, 0], seed_ref[bh, 1], (bh * n_q + i) * n_k + j,
-                interpret=interpret)
-
-        def draw(site, shape, row0, col0, stream, rb):
-            return common.kernel_bits_words(
-                seed_ref[bh, 2 * site], seed_ref[bh, 2 * site + 1], shape,
-                row0=row0, col0=col0, stream=stream, rand_bits=rb,
-                interpret=interpret)
+        draw = _kernel_draw(seed_ref, bh, (bh * n_q + i) * n_k + j, interpret)
 
         q0, k0 = q_offset + i * qb, j * kb
         valid = _position_mask((qb, kb), q0, k0, q_len=q_len, kv_len=Skv,
@@ -271,7 +289,7 @@ def flash_fwd_p(q, k, v, seeds, specs, *, scale, n_heads: int, n_kv: int,
         transcendentals=2 * BH * Sq * Skv,
         bytes_accessed=4 * (BH * Sq * (dk + 2 * dv + 2)
                             + BKV * Skv * (dk + dv)))
-    return pl.pallas_call(
+    out, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(BH, n_q, n_k),
@@ -279,19 +297,20 @@ def flash_fwd_p(q, k, v, seeds, specs, *, scale, n_heads: int, n_kv: int,
                       pl.BlockSpec((1, kb, dk), idx_kv),
                       pl.BlockSpec((1, kb, dv), idx_kv)],
             out_specs=[pl.BlockSpec((1, qb, dv), idx_q),
-                       pl.BlockSpec((1, qb), idx_ml),
-                       pl.BlockSpec((1, qb), idx_ml)],
+                       pl.BlockSpec((None, 1, qb), idx_ml),
+                       pl.BlockSpec((None, 1, qb), idx_ml)],
             scratch_shapes=[pltpu.VMEM((qb, dv), jnp.float32),
                             pltpu.VMEM((qb, 1), jnp.float32),
                             pltpu.VMEM((qb, 1), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((BH, Sq, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
-                   jax.ShapeDtypeStruct((BH, Sq), jnp.float32)],
+                   jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32)],
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_SEMANTICS),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_SEMANTICS, vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=cost,
     )(seeds, q, k, v)
+    return out, m.reshape(BH, Sq), l.reshape(BH, Sq)
 
 
 def flash_fwd_reference(q, k, v, seeds, specs, *, scale, n_heads: int,
@@ -363,7 +382,6 @@ def flash_bwd_dq_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
     qb, n_q = _blocks(Sq, q_block)
     kb, n_k = _blocks(Skv, kv_block)
     q_len = q_offset + Sq
-    any_stoch = spec_qk.stochastic or spec_dq.stochastic
 
     def idx_q(bh, i, j, *s):
         return (bh, i, 0)
@@ -372,7 +390,7 @@ def flash_bwd_dq_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
         return (_kv_of(bh, n_heads, n_kv), j, 0)
 
     def idx_ml(bh, i, j, *s):
-        return (bh, i)
+        return (bh, 0, i)
 
     def kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref,
                dq_ref, acc_scr):
@@ -382,16 +400,7 @@ def flash_bwd_dq_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
         def _init():
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        if any_stoch:
-            common.seed_kernel_prng_words(
-                seed_ref[bh, 0], seed_ref[bh, 1], (bh * n_q + i) * n_k + j,
-                interpret=interpret)
-
-        def draw(site, shape, row0, col0, stream, rb):
-            return common.kernel_bits_words(
-                seed_ref[bh, 2 * site], seed_ref[bh, 2 * site + 1], shape,
-                row0=row0, col0=col0, stream=stream, rand_bits=rb,
-                interpret=interpret)
+        draw = _kernel_draw(seed_ref, bh, (bh * n_q + i) * n_k + j, interpret)
 
         q0, k0 = q_offset + i * qb, j * kb
         valid = _position_mask((qb, kb), q0, k0, q_len=q_len, kv_len=Skv,
@@ -418,21 +427,21 @@ def flash_bwd_dq_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
                       pl.BlockSpec((1, kb, dk), idx_kv),
                       pl.BlockSpec((1, kb, dv), idx_kv),
                       pl.BlockSpec((1, qb, dv), idx_q),
-                      pl.BlockSpec((1, qb), idx_ml),
-                      pl.BlockSpec((1, qb), idx_ml),
-                      pl.BlockSpec((1, qb), idx_ml)],
+                      pl.BlockSpec((None, 1, qb), idx_ml),
+                      pl.BlockSpec((None, 1, qb), idx_ml),
+                      pl.BlockSpec((None, 1, qb), idx_ml)],
             out_specs=pl.BlockSpec((1, qb, dk), idx_q),
             scratch_shapes=[pltpu.VMEM((qb, dk), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, dk), jnp.float32),
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_SEMANTICS),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_SEMANTICS, vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=4 * BH * Sq * Skv * (dk + dv),
             transcendentals=BH * Sq * Skv,
             bytes_accessed=4 * (2 * BH * Sq * (dk + dv)
                                 + BH * Skv * (dk + dv))),
-    )(seeds, q, k, v, do, m, l, d)
+    )(seeds, q, k, v, do, *_res_rows(m, l, d))
 
 
 def flash_bwd_dkv_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
@@ -457,8 +466,6 @@ def flash_bwd_dkv_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
     qb, n_q = _blocks(Sq, q_block)
     kb, n_k = _blocks(Skv, kv_block)
     q_len = q_offset + Sq
-    any_stoch = (spec_qk.stochastic or spec_dk.stochastic
-                 or spec_dv.stochastic)
 
     def idx_q(bh, j, i, *s):
         return (bh, i, 0)
@@ -467,7 +474,7 @@ def flash_bwd_dkv_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
         return (_kv_of(bh, n_heads, n_kv), j, 0)
 
     def idx_ml(bh, j, i, *s):
-        return (bh, i)
+        return (bh, 0, i)
 
     def idx_out(bh, j, i, *s):
         return (bh, j, 0)
@@ -481,16 +488,9 @@ def flash_bwd_dkv_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
             dk_scr[...] = jnp.zeros_like(dk_scr)
             dv_scr[...] = jnp.zeros_like(dv_scr)
 
-        if any_stoch:
-            common.seed_kernel_prng_words(
-                seed_ref[bh, 0], seed_ref[bh, 1], (bh * n_k + j) * n_q + i,
-                interpret=interpret)
-
-        def draw(site, shape, row0, col0, stream, rb):
-            return common.kernel_bits_words(
-                seed_ref[bh, 2 * site], seed_ref[bh, 2 * site + 1], shape,
-                row0=row0, col0=col0, stream=stream, rand_bits=rb,
-                interpret=interpret)
+        # the forward's block id for this (q block, kv block): the qk
+        # recompute then redraws the forward's logit bits on the chip
+        draw = _kernel_draw(seed_ref, bh, (bh * n_q + i) * n_k + j, interpret)
 
         q0, k0 = q_offset + i * qb, j * kb
         valid = _position_mask((qb, kb), q0, k0, q_len=q_len, kv_len=Skv,
@@ -523,9 +523,9 @@ def flash_bwd_dkv_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
                       pl.BlockSpec((1, kb, dk), idx_kv),
                       pl.BlockSpec((1, kb, dv), idx_kv),
                       pl.BlockSpec((1, qb, dv), idx_q),
-                      pl.BlockSpec((1, qb), idx_ml),
-                      pl.BlockSpec((1, qb), idx_ml),
-                      pl.BlockSpec((1, qb), idx_ml)],
+                      pl.BlockSpec((None, 1, qb), idx_ml),
+                      pl.BlockSpec((None, 1, qb), idx_ml),
+                      pl.BlockSpec((None, 1, qb), idx_ml)],
             out_specs=[pl.BlockSpec((1, kb, dk), idx_out),
                        pl.BlockSpec((1, kb, dv), idx_out)],
             scratch_shapes=[pltpu.VMEM((kb, dk), jnp.float32),
@@ -533,14 +533,14 @@ def flash_bwd_dkv_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
         out_shape=[jax.ShapeDtypeStruct((BH, Skv, dk), jnp.float32),
                    jax.ShapeDtypeStruct((BH, Skv, dv), jnp.float32)],
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_SEMANTICS),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_SEMANTICS, vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=6 * BH * Sq * Skv * (dk + dv),
             transcendentals=BH * Sq * Skv,
             bytes_accessed=4 * (2 * BH * Sq * (dk + dv)
                                 + 2 * BH * Skv * (dk + dv))),
-    )(seeds, q, k, v, do, m, l, d)
+    )(seeds, q, k, v, do, *_res_rows(m, l, d))
 
 
 def flash_bwd_dq_reference(q, k, v, do, m, l, d, seeds, spec_qk, spec_dq,
@@ -669,7 +669,6 @@ def flash_decode_p(q, k, v, seeds, length, specs, *, scale,
     seeds = _check_seeds(seeds, BKV, 6)
     lens = jnp.asarray(length, jnp.int32).reshape(-1)[:1]
     kb, n_k = _blocks(Smax, kv_block)
-    any_stoch = any(s.stochastic for s in specs)
 
     def idx_q(b, j, *s):
         return (b, 0, 0)
@@ -688,16 +687,7 @@ def flash_decode_p(q, k, v, seeds, length, specs, *, scale,
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        if any_stoch:
-            common.seed_kernel_prng_words(
-                seed_ref[b, 0], seed_ref[b, 1], b * n_k + j,
-                interpret=interpret)
-
-        def draw(site, shape, row0, col0, stream, rb):
-            return common.kernel_bits_words(
-                seed_ref[b, 2 * site], seed_ref[b, 2 * site + 1], shape,
-                row0=row0, col0=col0, stream=stream, rand_bits=rb,
-                interpret=interpret)
+        draw = _kernel_draw(seed_ref, b, b * n_k + j, interpret)
 
         k_blk, v_blk = k_ref[0], v_ref[0]
         if kv_fmt is not None:
@@ -730,7 +720,7 @@ def flash_decode_p(q, k, v, seeds, length, specs, *, scale,
                             pltpu.VMEM((G, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BKV, G, dv), jnp.float32),
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=2 * BKV * G * Smax * (dk + dv),
@@ -788,7 +778,6 @@ def flash_decode_paged_p(q, k_pages, v_pages, seeds, lengths, tables, specs,
     if tables.ndim != 2 or tables.shape[0] != B:
         raise ValueError(f"tables must be ({B}, n_max), got {tables.shape}")
     n_max = tables.shape[1]
-    any_stoch = any(s.stochastic for s in specs)
 
     def idx_q(b, j, *s):
         return (b, 0, 0)
@@ -807,16 +796,10 @@ def flash_decode_paged_p(q, k_pages, v_pages, seeds, lengths, tables, specs,
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        if any_stoch:
-            common.seed_kernel_prng_words(
-                seed_ref[b, 0], seed_ref[b, 1], b * n_max + j,
-                interpret=interpret)
-
-        def draw(site, shape, row0, col0, stream, rb):
-            return common.kernel_bits_words(
-                seed_ref[b, 2 * site], seed_ref[b, 2 * site + 1], shape,
-                row0=row0, col0=col0, stream=stream, rand_bits=rb,
-                interpret=interpret)
+        # logical coordinates only (logical page, kv head): the row's words
+        # already key (request, position, kv head), and the batch slot and
+        # table width never enter — the engine's determinism contract
+        draw = _kernel_draw(seed_ref, b, j * n_kv + b % n_kv, interpret)
 
         k_blk, v_blk = k_ref[0], v_ref[0]
         if kv_fmt is not None:
@@ -849,7 +832,7 @@ def flash_decode_paged_p(q, k_pages, v_pages, seeds, lengths, tables, specs,
                             pltpu.VMEM((G, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BKV, G, dv), jnp.float32),
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=2 * BKV * G * n_max * page * (dk + dv),

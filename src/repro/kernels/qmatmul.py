@@ -306,7 +306,7 @@ def _qmm2d(a, b, rand, fmt, mode, eps, *, rand_bits, bm, bn, bk, bias, act,
     call_kwargs = dict(
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS_2D),
         cost_estimate=_cost(M, N, K, act=act, in_bytes=in_bytes,
                             out_bytes=out_bytes),
@@ -552,7 +552,7 @@ def _qmmb(a, b, rand, fmt, mode, eps, *, rand_bits, be, bm, bn, bk, act,
     call_kwargs = dict(
         out_shape=jax.ShapeDtypeStruct((E, M, N), out_dtype),
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS_BATCHED),
         cost_estimate=_cost(M, N, K, E=E, act=act, in_bytes=in_bytes,
                             out_bytes=out_bytes),
@@ -794,7 +794,7 @@ def _qmm_swiglu(x, wg, wu, rand, fmt, mode, eps, *, rand_bits, act, act_spec,
     call_kwargs = dict(
         out_shape=out_shapes,
         interpret=interpret,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS_2D),
         cost_estimate=pl.CostEstimate(
             flops=4 * M * N * K, bytes_accessed=in_bytes + out_bytes,

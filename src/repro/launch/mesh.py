@@ -13,21 +13,30 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.dist.sharding import MeshAxes
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding code here
+    places arrays with ``NamedSharding``s and ``with_sharding_constraint``
+    and lets the partitioner propagate the rest (jax's default axis type
+    is ``Explicit``, sharding-in-types, which that code does not use)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
     """1-device (or tiny) mesh so the distributed code paths run in tests."""
     n = len(jax.devices())
     data = max(1, n // model_axis)
-    return jax.make_mesh((data, model_axis), ("data", "model"))
+    return make_mesh((data, model_axis), ("data", "model"))
 
 
 def parse_mesh(spec: str):
@@ -55,7 +64,7 @@ def parse_mesh(spec: str):
             "set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{prod} (CPU) or pick a matching topology")
     axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def mesh_axes_for(mesh, *, batch_size: Optional[int] = None) -> MeshAxes:

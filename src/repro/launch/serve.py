@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduced as reduce_cfg
 from repro.launch import steps as steps_lib
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 
 
@@ -135,6 +136,7 @@ def main():
                          "and the cache is stored packed (uint8 codes); "
                          "overrides the policy's kv_cache_fmt")
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.arch, reduced=args.reduced, batch=args.batch,
         prompt_len=args.prompt_len, gen=args.gen,
         gemm_policy=args.gemm_policy, kv_cache_fmt=args.kv_cache_fmt)

@@ -264,13 +264,10 @@ def make_train_step(model, optimizer, *, grad_dtype=jnp.bfloat16,
     # The body is *manual over every mesh axis*: batch axes carry the
     # data-parallel split and the explicit rounded collectives; the other
     # axes (``model``) see replicated operands, so the per-shard loss/grad
-    # computation is redundantly replicated across them — semantically
-    # exact, and the robust choice on current jax (sharding constraints
-    # inside a partially-``auto`` manual region abort the XLA CPU
-    # partitioner; ``compat.shard_map(auto=...)`` is ready once that
-    # lands).  The ambient shard_act constraints are therefore disabled
+    # computation is redundantly replicated across them (semantically
+    # exact).  The ambient shard_act constraints are therefore disabled
     # inside (a manual region may not mention manual axes).
-    from repro.dist import codecs as codecs_lib, compat
+    from repro.dist import codecs as codecs_lib
     from repro.dist.collectives import wire_reduce
 
     def wire_body(params, key, step, batch, words, scale):
@@ -287,7 +284,7 @@ def make_train_step(model, optimizer, *, grad_dtype=jnp.bfloat16,
     def run_wire(params, opt_state, batch, scale):
         words = codecs_lib.wire_words(opt_state.key, opt_state.step)
         batch_spec = jax.tree.map(lambda _: P(batch_axes), batch)
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             wire_body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params), P(), P(),
                       batch_spec, P(), P()),

@@ -36,6 +36,7 @@ from repro.configs import get_config, reduced as reduce_cfg
 from repro.core import gd, rounding
 from repro.data import ShardedPipeline, make_token_pipeline
 from repro.launch import steps as steps_lib
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, mesh_axes_for, parse_mesh
 from repro.dist.sharding import (build_param_shardings,
                                  evenly_divisible_spec, set_mesh_axes)
@@ -378,6 +379,7 @@ def main():
                     help="sliding step window the restart budget is "
                          "counted over (0 = run-lifetime budget)")
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.arch, reduced=args.reduced, steps=args.steps, batch=args.batch,
         seq=args.seq, lr=args.lr, rounding_kind=args.rounding, fmt=args.fmt,
         eps=args.eps, ckpt_dir=args.ckpt_dir, update_path=args.update_path,

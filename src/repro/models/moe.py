@@ -27,7 +27,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.dist import compat
 from repro.dist.sharding import _axes, shard_act
 from repro.models import layers as L
 from repro.models.ffn import ffn_apply, ffn_init
@@ -192,7 +191,7 @@ def moe_apply(params, x, cfg, router_key=None,
                     y_all, d_idx * T_loc, T_loc, axis=0)
 
             tok_spec = P(dp, None)
-            y = compat.shard_map(
+            y = jax.shard_map(
                 serve_fn, mesh=mesh,
                 in_specs=(tok_spec, tok_spec, tok_spec,
                           P(fsdp, None, ax.model), P(fsdp, None, ax.model),
@@ -237,7 +236,7 @@ def moe_apply(params, x, cfg, router_key=None,
             return jax.lax.psum(y_, ax.model)
 
         tok_spec = P(dp, None)
-        y = compat.shard_map(
+        y = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(tok_spec, tok_spec, tok_spec,
                       P(ax.model, fsdp, None), P(ax.model, fsdp, None),

@@ -15,6 +15,7 @@ the params.
 from __future__ import annotations
 
 import functools
+import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -98,16 +99,17 @@ _BLOCK_INIT = {
 
 
 def init_blocks(key, cfg, plan) -> Dict[str, Any]:
-    """Stacked params per block type (leading dim = #occurrences)."""
+    """Stacked params per block type (leading dim = #occurrences).  Each
+    type folds a CRC of its name into the key: a stable tag, where
+    Python's ``hash`` of a string changes with every process."""
     counts = plan_counts(plan)
     out: Dict[str, Any] = {}
     for t, n in counts.items():
+        tkey = jax.random.fold_in(key, zlib.crc32(t.encode()) % (2 ** 31))
         if t == "shared_attn":
-            out["shared"] = _attn_block_init(
-                jax.random.fold_in(key, hash(t) % (2 ** 31)), cfg,
-                dense_ffn=True)
+            out["shared"] = _attn_block_init(tkey, cfg, dense_ffn=True)
             continue
-        keys = jax.random.split(jax.random.fold_in(key, hash(t) % (2 ** 31)), n)
+        keys = jax.random.split(tkey, n)
         stacked = [ _BLOCK_INIT[t](k, cfg) for k in keys ]
         out[t] = jax.tree.map(lambda *xs: jnp.stack(xs), *stacked)
     return out

@@ -96,9 +96,8 @@ def tree_rounded_update(params, grads, t, cfg: GDRounding, key, step,
     ax = _axes()
     if ax.active:
         from jax.sharding import PartitionSpec as P
-        from repro.dist import compat
         pspec = jax.tree.map(lambda _: P(), params)
-        return compat.shard_map(
+        return jax.shard_map(
             run, mesh=ax.mesh,
             in_specs=(pspec, pspec, P(), P()), out_specs=pspec,
             check_vma=False)(params, grads, key, step)
@@ -132,14 +131,12 @@ def tree_rounded_adam_update(params, grads, m, v, scal, cfg: GDRounding,
     ax = _axes()
     if ax.active:
         from jax.sharding import PartitionSpec as P
-        from repro.dist import compat
         pspec = jax.tree.map(lambda _: P(), params)
         in_specs = (pspec, pspec, P(), P(), P(), P(), P()) \
             + ((P(), P()) if kahan else ())
         out_specs = (pspec, P(), P()) + ((P(), P()) if kahan else ())
-        res = compat.shard_map(run, mesh=ax.mesh, in_specs=in_specs,
-                               out_specs=out_specs,
-                               check_vma=False)(*args)
+        res = jax.shard_map(run, mesh=ax.mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)(*args)
     else:
         res = run(*args)
     return res if kahan else res + (None, None)

@@ -38,7 +38,7 @@ import dataclasses
 from typing import Callable, FrozenSet, List, Optional, Sequence
 
 import jax
-from jax import core
+from jax.extend import core
 
 EMPTY: FrozenSet[str] = frozenset()
 

@@ -65,6 +65,7 @@ class RequestResult:
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     prompt_len: int = 0
+    slot: Optional[int] = None     # batch slot it was admitted to
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,6 +228,7 @@ class ContinuousBatchingEngine:
             lw = _layer_words(req.seed, self._n_layers)
             self._slots[free_slots[0]] = _Slot(req=req, pages=pages,
                                                layer_words=lw)
+            self.results[req.rid].slot = free_slots[0]
             self._mirror = None
             self._rr += 1
             scanned = 0             # fresh round after a successful admit

@@ -12,7 +12,8 @@ CheckpointManager, and failure handling:
   schedule-driven implementation; any ``step -> None`` callable works,
   and callables with an ``attach`` method are handed the loop so they
   can tamper with live state / checkpoints);
-* non-finite-loss / runtime-error circuit breaker: restore the latest
+* non-finite-loss / runtime-error circuit breaker (JAX's own runtime
+  errors, from the compiler or the device, are re-raised): restore the latest
   checkpoint, or — when nothing has been checkpointed yet — the pristine
   *initial* state snapshotted at construction (the in-flight ``self.state``
   may hold a half-applied, corrupted update).  Loss scaling is the
@@ -195,6 +196,10 @@ class TrainLoop:
                         self._init_state = None
                         self._init_pipeline = None
             except (FloatingPointError, RuntimeError) as e:
+                if isinstance(e, jax.errors.JaxRuntimeError):
+                    # a compiler or device error: replaying the step from
+                    # a checkpoint would only repeat it
+                    raise
                 restarts_total += 1
                 self._charge_restart(restart_log, step)
                 resumed = self._try_resume()
@@ -205,7 +210,7 @@ class TrainLoop:
         self._save(step, blocking=True)
         self.ckpt.wait()
         out = {"final_step": step, "restarts": restarts_total,
-               "history": self.history,
+               "history": self.history, "state": self.state,
                "mean_step_ms": 1e3 * total_t / max(total_n, 1)}
         if self.watchdog is not None:
             out["watchdog_events"] = list(self.watchdog.events)

@@ -10,6 +10,7 @@ import pytest
 from repro.checkpoint import CheckpointManager
 from repro.data import (ShardedPipeline, make_token_pipeline,
                         synthetic_binary_mnist, synthetic_mnist)
+from repro.launch.mesh import make_mesh
 from repro.train import TrainLoop, TrainLoopConfig
 
 
@@ -116,7 +117,7 @@ def test_checkpoint_atomicity_no_partial_dirs(tmp_path):
 def test_checkpoint_resharding_restore(tmp_path):
     """Elastic restore: re-place leaves under an explicit sharding."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     mgr = CheckpointManager(str(tmp_path))
     mgr.save(1, {"x": jnp.arange(8.0)}, blocking=True)
     sh = {"x": NamedSharding(mesh, P("data"))}

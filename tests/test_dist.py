@@ -88,17 +88,17 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.dist import compat
 from repro.dist.collectives import hierarchical_grad_reduce
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 g = jnp.arange(32.0).reshape(8, 4)
 spec = P(("pod", "data"), None)
 
 def f(x):
     return hierarchical_grad_reduce({"g": x}, mesh)["g"]
 
-out = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(spec,),
-                               out_specs=spec, check_vma=False))(g)
+out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(spec,),
+                            out_specs=spec, check_vma=False))(g)
 # mean over pod x data of the 4 shards
 want = np.asarray(g).reshape(4, 2, 4).mean(0).repeat(4, 0) * 0
 shards = np.asarray(g).reshape(4, 2, 4)
@@ -109,8 +109,8 @@ np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
 # compressed variant close to exact
 def fc(x):
     return hierarchical_grad_reduce({"g": x}, mesh, compress_pod=True)["g"]
-outc = jax.jit(compat.shard_map(fc, mesh=mesh, in_specs=(spec,),
-                                out_specs=spec, check_vma=False))(g)
+outc = jax.jit(jax.shard_map(fc, mesh=mesh, in_specs=(spec,),
+                             out_specs=spec, check_vma=False))(g)
 np.testing.assert_allclose(np.asarray(outc), want, rtol=0.05, atol=0.05)
 print("OK")
 """
@@ -205,7 +205,8 @@ x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
 y_ref, aux_ref = moe_lib.moe_apply(params, x, cfg)
 
 # EP path on a (data=2, model=4) mesh
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 ax = MeshAxes(mesh=mesh, batch=("data",))
 with set_mesh_axes(ax), mesh:
     y_ep, aux_ep = jax.jit(lambda p, x_: moe_lib.moe_apply(p, x_, cfg))(params, x)
@@ -242,7 +243,8 @@ x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
 cfg_dense = dataclasses.replace(cfg, moe_serve_layout=False)
 y_ref, aux_ref = moe_lib.moe_apply(params, x, cfg_dense)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 ax = MeshAxes(mesh=mesh, batch=("data",))
 with set_mesh_axes(ax), mesh:
     y_srv, aux_srv = jax.jit(lambda p, x_: moe_lib.moe_apply(p, x_, cfg))(params, x)

@@ -73,6 +73,30 @@ def test_counter_bits_bit_balance_and_stream_independence():
     assert abs(np.corrcoef(uw0, uw1)[0, 1]) < 5.0 / np.sqrt(n)
 
 
+@pytest.mark.parametrize("shape,row0,col0", [
+    ((7, 13), 3, 5), ((8, 128), 0, 0), ((3, 5, 9), 2, 7), ((4, 33), 1, None)])
+def test_interleaved_words_tpu_formulation_matches(monkeypatch, shape, row0,
+                                                   col0):
+    """The TPU formulation of the counter words (hash both columns of a
+    pair, pick by parity) is bit-identical to the CPU one (hash each pair
+    once, interleave); ``col0=None`` runs a traced offset."""
+    if len(shape) == 3:
+        w = jnp.asarray([[1, 2], [3, 4], [5, 6]], jnp.uint32)
+        k0, k1 = w[:, 0][:, None, None], w[:, 1][:, None, None]
+    else:
+        k0, k1 = jnp.uint32(9), jnp.uint32(8)
+
+    def words():
+        if col0 is None:
+            return jax.jit(lambda c: common._interleaved_words(
+                k0, k1, shape, row0, c, 2))(jnp.int32(11))
+        return common._interleaved_words(k0, k1, shape, row0, col0, 2)
+
+    cpu = np.asarray(words())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    np.testing.assert_array_equal(np.asarray(words()), cpu)
+
+
 def test_threefry_matches_jax_prf():
     """Our in-kernel Threefry-2x32 is bit-identical to jax.random's PRF."""
     from jax._src.prng import threefry_2x32

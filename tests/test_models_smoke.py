@@ -182,3 +182,26 @@ def test_mla_absorbed_decode_matches_naive():
     np.testing.assert_allclose(np.asarray(l1, np.float32),
                                np.asarray(l2, np.float32),
                                rtol=0.05, atol=0.05)
+
+
+def test_init_is_the_same_in_every_process():
+    """Parameters depend on the key alone: interpreters with different
+    string-hash seeds (``PYTHONHASHSEED``) build identical parameters."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = ("import hashlib, jax, numpy as np\n"
+            "from repro.configs import get_config, reduced\n"
+            "from repro.models import build_model\n"
+            "cfg = reduced(get_config('zamba2-1.2b'))\n"
+            "p = build_model(cfg).init(jax.random.PRNGKey(0))\n"
+            "print(hashlib.sha256(b''.join(np.asarray(x).tobytes()\n"
+            "      for x in jax.tree.leaves(p))).hexdigest())\n")
+    digests = [subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        check=True, timeout=300,
+        env={**os.environ, "PYTHONPATH": src, "PYTHONHASHSEED": seed}
+    ).stdout for seed in ("0", "1")]
+    assert digests[0] and digests[0] == digests[1]

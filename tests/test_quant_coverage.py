@@ -174,7 +174,8 @@ params = moe_lib.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                       jnp.float32) * 0.1
 quant = QP.make_ctx("binary8-paper", jax.random.PRNGKey(7))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 ax = MeshAxes(mesh=mesh, batch=("data",))
 """
 

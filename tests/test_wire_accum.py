@@ -20,6 +20,7 @@ from repro.core import rounding
 from repro.dist import codecs as codecs_lib
 from repro.dist.codecs import WireCodec, get_wire_codec, wire_codec_names
 from repro.dist.collectives import wire_bytes, wire_reduce
+from repro.launch.mesh import make_mesh
 from repro.optim.accumulate import (ACCUM_PRESETS, GradAccumulator,
                                     get_accumulator)
 
@@ -272,9 +273,7 @@ def test_wire_rn_zeroes_sr_preserves_shard_map_mesh8():
     """Satellite regression through the real collective: a small-gradient
     tree mean-reduced over dp=4 arrives as exact zero through the RN wire
     but survives (in expectation) through the SR wire."""
-    from repro.dist import compat
-
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     small = 1e-3
     # every participant holds the same tree: a scale-setting entry and
     # sub-deadband entries (scale = 1/127, deadband = scale/2 = 3.9e-3)
@@ -286,7 +285,7 @@ def test_wire_rn_zeroes_sr_preserves_shard_map_mesh8():
         def f(x, w):
             return wire_reduce({"g": x}, "data", codec=codec_name,
                                words=w)["g"]
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(spec, P()), out_specs=spec,
             check_vma=False))
 
@@ -318,7 +317,7 @@ def test_sharded_optimizer_step_bit_parity_mesh8(update_path):
     p_ref, s_ref = jax.jit(lambda p, g, s: opt.apply(p, g, s))(
         params, grads, state)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ax = mesh_axes_for(mesh, batch_size=8)
     sh = build_param_shardings(params, mesh, ax)
     ps = jax.device_put(params, sh)
@@ -351,7 +350,7 @@ def test_sharded_train_step_parity_mesh8():
     train_step = steps_lib.make_train_step(model, opt)
     p_ref, s_ref, m_ref = jax.jit(train_step)(params, state, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ax = mesh_axes_for(mesh, batch_size=8)
     sh = build_param_shardings(params, mesh, ax)
     ps = jax.device_put(params, sh)
@@ -387,7 +386,7 @@ def test_sharded_resume_bit_exact_mesh8(tmp_path):
     from repro.train import TrainLoop, TrainLoopConfig
 
     model, opt, params, state, _ = _tiny_setup()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ax = mesh_axes_for(mesh, batch_size=8)
     step = steps_lib.make_train_step(
         model, opt, wire_spec="e4m3-sr", mesh=mesh, ax=ax, accum_steps=2,
@@ -444,7 +443,7 @@ def test_wire_train_loss_matches_unsharded_mesh8():
     from repro.launch.mesh import mesh_axes_for
 
     model, opt, params, state, batch = _tiny_setup()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ax = mesh_axes_for(mesh, batch_size=8)
     wired = steps_lib.make_train_step(model, opt, wire_spec="e4m3-sr",
                                       mesh=mesh, ax=ax, accum_steps=2)
