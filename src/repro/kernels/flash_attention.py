@@ -305,6 +305,7 @@ def flash_fwd_p(q, k, v, seeds, specs, *, scale, n_heads: int, n_kv: int,
         out_shape=[jax.ShapeDtypeStruct((BH, Sq, dv), jnp.float32),
                    jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
                    jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32)],
+        name="flash_fwd",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS, vmem_limit_bytes=_VMEM_LIMIT),
@@ -433,6 +434,7 @@ def flash_bwd_dq_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
             out_specs=pl.BlockSpec((1, qb, dk), idx_q),
             scratch_shapes=[pltpu.VMEM((qb, dk), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, dk), jnp.float32),
+        name="flash_dq",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS, vmem_limit_bytes=_VMEM_LIMIT),
@@ -532,6 +534,7 @@ def flash_bwd_dkv_p(q, k, v, do, m, l, d, seeds, spec_qk: RoundingSpec,
                             pltpu.VMEM((kb, dv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((BH, Skv, dk), jnp.float32),
                    jax.ShapeDtypeStruct((BH, Skv, dv), jnp.float32)],
+        name="flash_dkv",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS, vmem_limit_bytes=_VMEM_LIMIT),
@@ -719,6 +722,7 @@ def flash_decode_p(q, k, v, seeds, length, specs, *, scale,
                             pltpu.VMEM((G, 1), jnp.float32),
                             pltpu.VMEM((G, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BKV, G, dv), jnp.float32),
+        name="flash_decode",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
@@ -831,6 +835,7 @@ def flash_decode_paged_p(q, k_pages, v_pages, seeds, lengths, tables, specs,
                             pltpu.VMEM((G, 1), jnp.float32),
                             pltpu.VMEM((G, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BKV, G, dv), jnp.float32),
+        name="paged_decode",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),

@@ -100,6 +100,7 @@ def fused_qupdate_p(x, g, t, bits3, cfg: GDRounding,
                   bspec, bspec, bspec, bspec, bspec],
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct(xf.shape, jnp.float32),
+        name="fused_qupdate",
         interpret=interpret,
     )(t_arr, xf, gf, b1, b2, b3)
     return out.reshape(-1)[: x.size].reshape(shape)
@@ -153,6 +154,7 @@ def fused_qupdate_prng_p(x, g, t, seed, cfg: GDRounding,
             out_specs=bspec,
         ),
         out_shape=jax.ShapeDtypeStruct(xf.shape, jnp.float32),
+        name="fused_update",
         interpret=interpret,
     )(seed, t_arr, xf, gf)
     return out.reshape(-1)[: x.size].reshape(shape)
@@ -295,6 +297,7 @@ def fused_qadam_prng_p(x, g, m, v, scal, seed, cfg: GDRounding,
             out_specs=[bspec] * len(out_shape),
         ),
         out_shape=out_shape,
+        name="fused_qadam",
         interpret=interpret,
     )(seed, scal, *operands)
     return tuple(o.reshape(-1)[:n] for o in outs)

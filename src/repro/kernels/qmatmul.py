@@ -305,6 +305,7 @@ def _qmm2d(a, b, rand, fmt, mode, eps, *, rand_bits, bm, bn, bk, bias, act,
                          else 4)
     call_kwargs = dict(
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        name="qmatmul",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS_2D),
@@ -551,6 +552,7 @@ def _qmmb(a, b, rand, fmt, mode, eps, *, rand_bits, be, bm, bn, bk, act,
                              if pack_fmt is not None else 4)
     call_kwargs = dict(
         out_shape=jax.ShapeDtypeStruct((E, M, N), out_dtype),
+        name="qmatmul_batched",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS_BATCHED),
@@ -793,6 +795,7 @@ def _qmm_swiglu(x, wg, wu, rand, fmt, mode, eps, *, rand_bits, act, act_spec,
                                   if res_fmt is not None else 4)
     call_kwargs = dict(
         out_shape=out_shapes,
+        name="qmatmul_glu",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS_2D),

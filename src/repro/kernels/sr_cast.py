@@ -103,6 +103,7 @@ def sr_cast_p(x, bits, fmt, mode: str, eps: float = 0.0, v=None,
             in_specs=[bspec, bspec, bspec],
             out_specs=bspec,
             out_shape=jax.ShapeDtypeStruct(xf.shape, jnp.float32),
+            name="sr_cast",
             interpret=interpret,
         )(xf, bitsf, vf)
     else:
@@ -114,6 +115,7 @@ def sr_cast_p(x, bits, fmt, mode: str, eps: float = 0.0, v=None,
             in_specs=[bspec, bspec],
             out_specs=bspec,
             out_shape=jax.ShapeDtypeStruct(xf.shape, jnp.float32),
+            name="sr_cast",
             interpret=interpret,
         )(xf, bitsf)
     return out.reshape(-1)[: x.size].reshape(shape)
@@ -189,6 +191,7 @@ def sr_cast_prng_p(x, seed, fmt, mode: str, eps: float = 0.0, v=None,
             out_specs=bspec,
         ),
         out_shape=jax.ShapeDtypeStruct(xf.shape, jnp.float32),
+        name="sr_cast_prng",
         interpret=interpret,
     )(seed, *operands)
     return out.reshape(-1)[: x.size].reshape(shape)

@@ -133,6 +133,14 @@ def make_train_step(model, optimizer, *, grad_dtype=jnp.bfloat16,
     the numeric-health telemetry of `health/monitor.py` rides the metrics
     dict (``h_*`` keys).  With both left ``None`` the classic 3-arg step
     is returned, bit-identical to before.
+
+    Two ``jax.named_scope``s label the compiled step's operations (their
+    ``op_name`` metadata, which a profiler trace carries): ``step.forward``
+    around the model loss, so autodiff names the backward pass
+    ``transpose(jvp(step.forward))`` and a ``jax.checkpoint`` names the
+    recomputed forward ``.../checkpoint/rematted_computation/...``; and
+    ``step.update`` around the optimizer update with its tail (unscale,
+    finiteness check, skip-step select, health telemetry).
     """
     if gemm_policy is not None:
         model = build_model(dataclasses.replace(model.cfg,
@@ -163,7 +171,8 @@ def make_train_step(model, optimizer, *, grad_dtype=jnp.bfloat16,
 
         def one_microbatch(mb, rng):
             def loss_fn(p):
-                loss, aux = model.loss_fn(p, mb, rng=rng)
+                with jax.named_scope("step.forward"):
+                    loss, aux = model.loss_fn(p, mb, rng=rng)
                 # differentiate the *scaled* loss; report the true one
                 out = loss if scale is None else loss * scale
                 return out, (loss, aux)
@@ -212,6 +221,7 @@ def make_train_step(model, optimizer, *, grad_dtype=jnp.bfloat16,
         if not batch_axes:
             codec = None     # single-participant wire: nothing to round
 
+    @jax.named_scope("step.update")
     def apply_update(params, opt_state, carry, grads, metrics):
         """Extended-step tail shared by the plain and wire paths:
         unscale → overflow skip-step + scale update → health telemetry →
@@ -247,7 +257,9 @@ def make_train_step(model, optimizer, *, grad_dtype=jnp.bfloat16,
         def train_step(params, opt_state, batch):
             grads, metrics = grads_and_metrics(
                 params, opt_state.key, opt_state.step, batch)
-            new_params, new_state = optimizer.apply(params, grads, opt_state)
+            with jax.named_scope("step.update"):
+                new_params, new_state = optimizer.apply(params, grads,
+                                                        opt_state)
             return new_params, new_state, metrics
 
         if not extras:
@@ -296,7 +308,8 @@ def make_train_step(model, optimizer, *, grad_dtype=jnp.bfloat16,
     def train_step(params, opt_state, batch):
         grads, metrics = run_wire(params, opt_state, batch,
                                   jnp.float32(1.0))
-        new_params, new_state = optimizer.apply(params, grads, opt_state)
+        with jax.named_scope("step.update"):
+            new_params, new_state = optimizer.apply(params, grads, opt_state)
         return new_params, new_state, metrics
 
     if not extras:

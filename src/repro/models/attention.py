@@ -190,19 +190,21 @@ def attn_apply(params, x, positions, cfg, *, causal=True,
         if kv_packed:
             k_st = KC.pack_block(k_st, spec.fmt)
             v_st = KC.pack_block(v_st, spec.fmt)
-        k_pages = PC.paged_append(cache.k_pages, cache.tables, cache.lengths,
-                                  cache.append, k_st)
-        v_pages = PC.paged_append(cache.v_pages, cache.tables, cache.lengths,
-                                  cache.append, v_st)
+        with jax.named_scope("attn.kv_append"):
+            k_pages = PC.paged_append(cache.k_pages, cache.tables,
+                                      cache.lengths, cache.append, k_st)
+            v_pages = PC.paged_append(cache.v_pages, cache.tables,
+                                      cache.lengths, cache.append, v_st)
         new_len = cache.lengths + jnp.where(cache.append, S, 0).astype(
             jnp.int32)
         new_cache = cache._replace(k_pages=k_pages, v_pages=v_pages,
                                    lengths=new_len)
         if S == 1 and pol is not None and not pol.attn_sites_identity:
-            out = PA.qattn_decode_paged(
-                q, k_pages, v_pages, new_len, cache.tables, cache.words,
-                pol, scale=scale, window=cfg.sliding_window,
-                kv_fmt=spec.fmt if kv_packed else None)
+            with jax.named_scope("attn.paged_decode"):
+                out = PA.qattn_decode_paged(
+                    q, k_pages, v_pages, new_len, cache.tables, cache.words,
+                    pol, scale=scale, window=cfg.sliding_window,
+                    kv_fmt=spec.fmt if kv_packed else None)
         else:
             k_f = PC.paged_gather(k_pages, cache.tables)
             v_f = PC.paged_gather(v_pages, cache.tables)

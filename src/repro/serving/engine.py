@@ -28,6 +28,26 @@ across arrival schedules, slot placements, co-tenants and batch widths
 (tests/test_serving.py).  Policies that also round the GEMM projections
 stay deterministic per engine configuration but are schedule-dependent,
 exactly like the fixed-batch driver.
+
+Profiler spans (``jax.profiler.TraceAnnotation``: written into an active
+``jax.profiler`` trace on the device events' clock, close to free when no
+trace is active; they add no sync and no device work):
+
+* ``engine.step`` — one iteration, the whole of ``step()``;
+* ``engine.admit`` — admission (``_admit``);
+* ``engine.decode`` (``active`` = slots decoded) — the batched decode:
+  table/word mirrors, lengths, host-to-device copies, the dispatch of the
+  ``engine_decode`` program and the emission of its tokens;
+* ``engine.decode.wait`` — inside it, the host blocked on the sampled
+  tokens;
+* ``engine.prefill`` (``rid``, ``tokens``) — one prefill chunk: its cache
+  view, host-to-device copies and the dispatch of ``engine_prefill``;
+* ``engine.prefill.wait`` (``rid``) — inside a request's last chunk, the
+  host blocked on its first token.
+
+The two jitted programs are named ``engine_decode`` and
+``engine_prefill``, so their device modules are ``jit_engine_decode`` and
+``jit_engine_prefill`` in a trace.
 """
 from __future__ import annotations
 
@@ -46,6 +66,8 @@ import numpy as np
 from repro.precision import attention as PA
 from repro.serving.paged_cache import (BlockAllocator, PagedKVCache,
                                        init_paged_cache, request_words)
+
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -110,7 +132,13 @@ def _layer_words(seed: int, n_layers: int) -> np.ndarray:
 def _jitted_step(model):
     fns = _STEP_CACHE.get(model)
     if fns is None:
-        step = jax.jit(model.decode_step, donate_argnums=(1,),
+        # the prefill chunk step (its Python name is the program's name)
+        def engine_prefill(params, caches, tokens, pos, rng=None,
+                           compute_logits=True):
+            return model.decode_step(params, caches, tokens, pos, rng=rng,
+                                     compute_logits=compute_logits)
+
+        step = jax.jit(engine_prefill, donate_argnums=(1,),
                        static_argnames=("compute_logits",))
 
         # decode-path wrapper: argmax inside the jit (sampling on device
@@ -118,8 +146,8 @@ def _jitted_step(model):
         # and ONLY the pools donated — the tables/words mirrors are reused
         # across calls, so donating the whole cache pytree would delete
         # them out from under the next iteration
-        def decode(params, k_pages, v_pages, tables, lengths, words,
-                   append, tokens, pos, rng):
+        def engine_decode(params, k_pages, v_pages, tables, lengths, words,
+                          append, tokens, pos, rng):
             cache = PagedKVCache(k_pages=k_pages, v_pages=v_pages,
                                  tables=tables, lengths=lengths,
                                  words=words, append=append)
@@ -128,7 +156,7 @@ def _jitted_step(model):
             return (jnp.argmax(logits[:, -1], axis=-1),
                     nc["attn"].k_pages, nc["attn"].v_pages)
 
-        fns = (step, jax.jit(decode, donate_argnums=(1, 2)))
+        fns = (step, jax.jit(engine_decode, donate_argnums=(1, 2)))
         _STEP_CACHE[model] = fns
     return fns
 
@@ -304,16 +332,20 @@ class ContinuousBatchingEngine:
                     continue
                 lo, hi = slot.prefilled, slot.prefilled + chunk
                 last = hi == len(slot.req.prompt)
-                toks = np.asarray(slot.req.prompt[lo:hi], np.int32)[None]
-                logits = self._call([i], np.ones((1,), bool), toks,
-                                    compute_logits=last)
+                rid = slot.req.rid
+                with _span("engine.prefill", rid=rid, tokens=chunk):
+                    toks = np.asarray(slot.req.prompt[lo:hi], np.int32)[None]
+                    logits = self._call([i], np.ones((1,), bool), toks,
+                                        compute_logits=last)
+                    if last:
+                        with _span("engine.prefill.wait", rid=rid):
+                            tok = int(jnp.argmax(logits[0, -1]))
                 slot.prefilled = hi
                 slot.length += chunk
                 spent += chunk
                 self.prefill_tokens += chunk
                 progressed = True
                 if last:
-                    tok = int(jnp.argmax(logits[0, -1]))
                     self._emit(i, tok)
         return spent
 
@@ -332,8 +364,6 @@ class ContinuousBatchingEngine:
         idx = list(range(self.cfg.n_slots))
         active = np.array([s is not None and s.cur_token >= 0
                            for s in self._slots], bool)
-        if not active.any():
-            return
         tokens = np.array([[s.cur_token if s is not None and s.cur_token >= 0
                             else 0] for s in self._slots], np.int32)
         # full-width fast path: tables/words device mirrors change only on
@@ -358,23 +388,28 @@ class ContinuousBatchingEngine:
             self._mirror[1],
             jnp.asarray(np.broadcast_to(active, (L, ec.n_slots))),
             jnp.asarray(tokens), jnp.asarray(lengths), self._tick_rng())
-        nxt = np.asarray(nxt)
+        with _span("engine.decode.wait"):
+            nxt = np.asarray(nxt)
         for i in idx:
             if active[i]:
                 self._slots[i].length += 1
                 self.decode_tokens += 1
                 self._emit(i, int(nxt[i]))
 
+    @functools.partial(jax.profiler.annotate_function, name="engine.step")
     def step(self) -> List[int]:
         """One engine iteration: admit → batched decode → prefill chunks.
         Returns the rids finished this iteration."""
         before = {rid for rid, r in self.results.items()
                   if r.finish_time is not None}
-        self._admit()
+        with _span("engine.admit"):
+            self._admit()
         budget = self.cfg.token_budget
         n_active = sum(1 for s in self._slots
                        if s is not None and s.cur_token >= 0)
-        self._decode_batch()
+        if n_active:
+            with _span("engine.decode", active=n_active):
+                self._decode_batch()
         budget = max(0, budget - n_active)
         self._prefill_chunks(budget)
         self.iterations += 1
