@@ -734,6 +734,24 @@ def flash_decode_p(q, k, v, seeds, length, specs, *, scale,
     )(seeds, lens, q, k, v)
 
 
+def _live_pages(length, page: int, window: int):
+    """First and last logical page of a request that hold a position
+    ``_decode_mask`` admits (both 0 for an empty request, which has
+    none): pages ``[first, last]`` are the request's live range."""
+    last = jnp.maximum(length - 1, 0) // page
+    first = jnp.maximum(length - window, 0) // page if window else 0
+    return first, last
+
+
+def paged_kv_block(b, j, lengths, tables, *, page: int, window: int = 0):
+    """Physical page that grid step ``(b, j)`` of
+    :func:`flash_decode_paged_p` reads: logical page ``j`` of request
+    ``b`` clamped into its live range.  Steps outside the range name the
+    block the step before them named, so the pipeline issues no copy."""
+    first, last = _live_pages(lengths[b], page, window)
+    return tables[b, jnp.clip(j, first, last)]
+
+
 def flash_decode_paged_p(q, k_pages, v_pages, seeds, lengths, tables, specs,
                          *, scale, n_kv: int, window: int = 0, kv_fmt=None,
                          interpret=None):
@@ -747,18 +765,31 @@ def flash_decode_paged_p(q, k_pages, v_pages, seeds, lengths, tables, specs,
     rows per request *including* the token being decoded; tables:
     (B, n_max) int32 logical→physical page ids (both ride scalar prefetch,
     so the index map DMAs exactly the request's pages — the vLLM paged-
-    attention pattern).  Table entries past a request's allocation must
-    point at *some* valid page (the allocator's scratch page 0): their
-    logical positions are ≥ length, so they are fully masked and — because
-    a fully-masked block contributes exactly 0 to the online softmax and
-    ``corr == 1`` — bit-neutral.  Hence with ``page == kv_block`` the
-    result is bit-identical to :func:`flash_decode_p` on the contiguously
-    gathered cache, regardless of the physical page placement.
+    attention pattern).
+
+    Grid ``(B, n_max)``: one step per (request, logical page), all KV
+    heads of the page at once — the ``(KV, page, d)`` block at block
+    index ``tables[b, j]`` is the page's heads, adjacent in the pool, so
+    one copy brings them, and the step runs the heads in turn.  Only the
+    request's live pages cost anything (:func:`paged_kv_block`): pages
+    past ``length``, and with ``window`` pages wholly before the window,
+    are neither copied (the index map clamps them onto a live page) nor
+    computed (``pl.when``).  Skipping is bit-neutral: a fully-masked block
+    contributes exactly 0 to the online softmax with ``corr == 1`` (or
+    leaves the untouched ``m = -inf, l = 0, acc = 0`` state as it was),
+    and each draw is keyed by its own block (below), so no other page's
+    bits move.  Table entries past a request's allocation must still
+    point at *some* valid page (the allocator's scratch page 0); their
+    content is never used.  Hence with ``page == kv_block`` the result is
+    bit-identical to :func:`flash_decode_p` on the contiguously gathered
+    cache, regardless of the physical page placement; an empty request
+    (length 0) returns the rounded zero.
 
     Randomness discipline: draws are keyed by the *logical* kv-block index
-    (stream = logical page, col0 = logical position), never the physical
-    page id, so a request's rounding stream is placement-invariant.
-    Returns (B·KV, G, dv) float32.
+    (stream = logical page, col0 = logical position; on the chip the
+    block id ``j·KV + h``) and the row ``b·KV + h`` of the seeds, never
+    the physical page id, so a request's rounding stream is
+    placement-invariant.  Returns (B·KV, G, dv) float32.
     """
     if interpret is None:
         interpret = common.default_interpret()
@@ -787,12 +818,21 @@ def flash_decode_paged_p(q, k_pages, v_pages, seeds, lengths, tables, specs,
         return (b, 0, 0)
 
     def idx_kv(b, j, seed_ref, len_ref, tbl_ref):
-        return (tbl_ref[b // n_kv, j] * n_kv + b % n_kv, 0, 0)
+        return (paged_kv_block(b, j, len_ref, tbl_ref, page=page,
+                               window=window), 0, 0)
 
     def kernel(seed_ref, len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
                acc_scr, m_scr, l_scr):
         b, j = pl.program_id(0), pl.program_id(1)
-        length = len_ref[b // n_kv]
+        length = len_ref[b]
+        first, last = _live_pages(length, page, window)
+
+        # logical coordinates only (logical page, kv head): the row's words
+        # already key (request, position, kv head), and the batch slot and
+        # table width never enter — the engine's determinism contract
+        def draw(h):
+            return _kernel_draw(seed_ref, b * n_kv + h, j * n_kv + h,
+                                interpret)
 
         @pl.when(j == 0)
         def _init():
@@ -800,40 +840,40 @@ def flash_decode_paged_p(q, k_pages, v_pages, seeds, lengths, tables, specs,
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        # logical coordinates only (logical page, kv head): the row's words
-        # already key (request, position, kv head), and the batch slot and
-        # table width never enter — the engine's determinism contract
-        draw = _kernel_draw(seed_ref, b, j * n_kv + b % n_kv, interpret)
-
-        k_blk, v_blk = k_ref[0], v_ref[0]
-        if kv_fmt is not None:
-            k_blk = common.unpack_block(k_blk, kv_fmt)
-            v_blk = common.unpack_block(v_blk, kv_fmt)
-        k0 = j * page                       # logical position of the block
-        valid = _decode_mask((G, page), k0, length, window)
-        m_new, l_new, acc_new = _fwd_block(
-            specs, scale, q_ref[0], k_blk, v_blk, valid, 0, k0,
-            length, j, draw, m_scr[...], l_scr[...], acc_scr[...])
-        m_scr[...] = m_new
-        l_scr[...] = l_new
-        acc_scr[...] = acc_new
+        @pl.when((length > 0) & (j >= first) & (j <= last))
+        def _page():
+            k0 = j * page                   # logical position of the block
+            valid = _decode_mask((G, page), k0, length, window)
+            for h in range(n_kv):
+                k_blk, v_blk = k_ref[h], v_ref[h]
+                if kv_fmt is not None:
+                    k_blk = common.unpack_block(k_blk, kv_fmt)
+                    v_blk = common.unpack_block(v_blk, kv_fmt)
+                m_new, l_new, acc_new = _fwd_block(
+                    specs, scale, q_ref[h], k_blk, v_blk, valid, 0, k0,
+                    length, j, draw(h), m_scr[h], l_scr[h], acc_scr[h])
+                m_scr[h] = m_new
+                l_scr[h] = l_new
+                acc_scr[h] = acc_new
 
         @pl.when(j == n_max - 1)
         def _emit():
-            o_ref[0] = _fwd_finish(specs, acc_scr[...], l_scr[...], 0, draw)
+            for h in range(n_kv):
+                o_ref[h] = _fwd_finish(specs, acc_scr[h], l_scr[h], 0,
+                                       draw(h))
 
     kv_bytes = common.pack_bytes(kv_fmt) if kv_fmt is not None else 4
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(BKV, n_max),
-            in_specs=[pl.BlockSpec((1, G, dk), idx_q),
-                      pl.BlockSpec((1, page, dk), idx_kv),
-                      pl.BlockSpec((1, page, dv), idx_kv)],
-            out_specs=pl.BlockSpec((1, G, dv), idx_q),
-            scratch_shapes=[pltpu.VMEM((G, dv), jnp.float32),
-                            pltpu.VMEM((G, 1), jnp.float32),
-                            pltpu.VMEM((G, 1), jnp.float32)]),
+            num_scalar_prefetch=3, grid=(B, n_max),
+            in_specs=[pl.BlockSpec((n_kv, G, dk), idx_q),
+                      pl.BlockSpec((n_kv, page, dk), idx_kv),
+                      pl.BlockSpec((n_kv, page, dv), idx_kv)],
+            out_specs=pl.BlockSpec((n_kv, G, dv), idx_q),
+            scratch_shapes=[pltpu.VMEM((n_kv, G, dv), jnp.float32),
+                            pltpu.VMEM((n_kv, G, 1), jnp.float32),
+                            pltpu.VMEM((n_kv, G, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BKV, G, dv), jnp.float32),
         name="paged_decode",
         interpret=interpret,

@@ -35,8 +35,10 @@ trace is active; they add no sync and no device work):
 
 * ``engine.step`` — one iteration, the whole of ``step()``;
 * ``engine.admit`` — admission (``_admit``);
-* ``engine.decode`` (``active`` = slots decoded) — the batched decode:
-  table/word mirrors, lengths, host-to-device copies, the dispatch of the
+* ``engine.decode`` (``active`` = slots decoded; ``live_pages`` = the
+  pages the paged kernel walks, each slot's ``ceil(length / page)`` with
+  the new token, of ``table_pages`` = slots x table width) — the batched
+  decode: table/word mirrors, host-to-device copies, the dispatch of the
   ``engine_decode`` program and the emission of its tokens;
 * ``engine.decode.wait`` — inside it, the host blocked on the sampled
   tokens;
@@ -362,39 +364,47 @@ class ContinuousBatchingEngine:
 
     def _decode_batch(self) -> None:
         idx = list(range(self.cfg.n_slots))
+        ec, L = self.cfg, self._n_layers
         active = np.array([s is not None and s.cur_token >= 0
                            for s in self._slots], bool)
-        tokens = np.array([[s.cur_token if s is not None and s.cur_token >= 0
-                            else 0] for s in self._slots], np.int32)
-        # full-width fast path: tables/words device mirrors change only on
-        # admit/release, so reuse them; lengths/append are per-call
-        ec, L = self.cfg, self._n_layers
-        if self._mirror is None:
-            tables = np.zeros((ec.n_slots, ec.max_pages_per_request),
-                              np.int32)
-            words = np.zeros((L, ec.n_slots, 2), np.uint32)
-            for i, slot in enumerate(self._slots):
-                if slot is not None:
-                    tables[i, :len(slot.pages)] = slot.pages
-                    words[:, i] = slot.layer_words
-            self._mirror = (
-                jnp.asarray(np.broadcast_to(tables, (L,) + tables.shape)),
-                jnp.asarray(words))
         lengths = np.array([s.length if s is not None else 0
                             for s in self._slots], np.int32)
-        nxt, self._k_pages, self._v_pages = self._decode_fn(
-            self.params, self._k_pages, self._v_pages, self._mirror[0],
-            jnp.asarray(np.broadcast_to(lengths, (L, ec.n_slots))),
-            self._mirror[1],
-            jnp.asarray(np.broadcast_to(active, (L, ec.n_slots))),
-            jnp.asarray(tokens), jnp.asarray(lengths), self._tick_rng())
-        with _span("engine.decode.wait"):
-            nxt = np.asarray(nxt)
-        for i in idx:
-            if active[i]:
-                self._slots[i].length += 1
-                self.decode_tokens += 1
-                self._emit(i, int(nxt[i]))
+        # the pages the paged kernel walks (each slot's length with the new
+        # token) against the block table's width
+        live_pages = int(np.sum(-(-(lengths + active) // ec.page_size)))
+        with _span("engine.decode", active=int(active.sum()),
+                   live_pages=live_pages,
+                   table_pages=ec.n_slots * ec.max_pages_per_request):
+            tokens = np.array([[s.cur_token if s is not None
+                                and s.cur_token >= 0 else 0]
+                               for s in self._slots], np.int32)
+            # full-width fast path: tables/words device mirrors change only
+            # on admit/release, so reuse them; lengths/append are per-call
+            if self._mirror is None:
+                tables = np.zeros((ec.n_slots, ec.max_pages_per_request),
+                                  np.int32)
+                words = np.zeros((L, ec.n_slots, 2), np.uint32)
+                for i, slot in enumerate(self._slots):
+                    if slot is not None:
+                        tables[i, :len(slot.pages)] = slot.pages
+                        words[:, i] = slot.layer_words
+                self._mirror = (
+                    jnp.asarray(np.broadcast_to(tables,
+                                                (L,) + tables.shape)),
+                    jnp.asarray(words))
+            nxt, self._k_pages, self._v_pages = self._decode_fn(
+                self.params, self._k_pages, self._v_pages, self._mirror[0],
+                jnp.asarray(np.broadcast_to(lengths, (L, ec.n_slots))),
+                self._mirror[1],
+                jnp.asarray(np.broadcast_to(active, (L, ec.n_slots))),
+                jnp.asarray(tokens), jnp.asarray(lengths), self._tick_rng())
+            with _span("engine.decode.wait"):
+                nxt = np.asarray(nxt)
+            for i in idx:
+                if active[i]:
+                    self._slots[i].length += 1
+                    self.decode_tokens += 1
+                    self._emit(i, int(nxt[i]))
 
     @functools.partial(jax.profiler.annotate_function, name="engine.step")
     def step(self) -> List[int]:
@@ -408,8 +418,7 @@ class ContinuousBatchingEngine:
         n_active = sum(1 for s in self._slots
                        if s is not None and s.cur_token >= 0)
         if n_active:
-            with _span("engine.decode", active=n_active):
-                self._decode_batch()
+            self._decode_batch()
         budget = max(0, budget - n_active)
         self._prefill_chunks(budget)
         self.iterations += 1

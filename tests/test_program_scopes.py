@@ -54,11 +54,19 @@ def _requests():
 
 @pytest.fixture(scope="module")
 def traced(served, tmp_path_factory):
-    """A warm engine's run under the profiler: (engine, iterations it
-    ran, host spans, modules the CPU ran)."""
+    """A warm engine's run under the profiler: (engine, host spans,
+    modules the CPU ran, the kernel lengths of each decode call)."""
     model, params = served
     _engine(model, params).run(_requests())           # compile outside
     eng = _engine(model, params)
+    kernel_lengths, decode = [], eng._decode_fn
+
+    def spy(params, kp, vp, tables, lengths, words, append, *rest):
+        # the paged kernel sees each slot's length plus its appended token
+        kernel_lengths.append(np.asarray(lengths)[0]
+                              + np.asarray(append)[0])
+        return decode(params, kp, vp, tables, lengths, words, append, *rest)
+    eng._decode_fn = spy
     d = tmp_path_factory.mktemp("trace")
     with jax.profiler.trace(str(d)):
         eng.run(_requests())
@@ -75,7 +83,7 @@ def traced(served, tmp_path_factory):
                 for k, v in ev.stats:
                     if k == "hlo_module":
                         modules.add(v)
-    return eng, spans, modules
+    return eng, spans, modules, kernel_lengths
 
 
 def _inside(inner, outer):
@@ -83,7 +91,7 @@ def _inside(inner, outer):
 
 
 def test_engine_spans_nest(traced):
-    eng, spans, _ = traced
+    eng, spans, *_ = traced
     names = {s.name for s in spans}
     assert names == {"engine.step", "engine.admit", "engine.decode",
                      "engine.decode.wait", "engine.prefill",
@@ -108,7 +116,7 @@ def test_engine_spans_nest(traced):
 
 
 def test_engine_spans_count_the_engine_work(traced):
-    eng, spans, _ = traced
+    eng, spans, *_ = traced
 
     def named(n):
         return [s for s in spans if s.name == n]
@@ -127,14 +135,31 @@ def test_engine_spans_count_the_engine_work(traced):
         eng.decode_tokens
 
 
+def test_engine_decode_span_counts_live_pages(traced):
+    eng, spans, _, kernel_lengths = traced
+    decodes = [s for s in spans if s.name == "engine.decode"]
+    page, width = eng.cfg.page_size, eng.cfg.max_pages_per_request
+    assert len(decodes) == len(kernel_lengths)
+    for span, lengths in zip(sorted(decodes, key=lambda s: s.start),
+                             kernel_lengths):
+        assert span.args["live_pages"] == sum(-(-int(n) // page)
+                                              for n in lengths)
+        assert span.args["table_pages"] == eng.cfg.n_slots * width
+    # a request longer than a page was seen, and no call walked the table
+    assert max(s.args["live_pages"] for s in decodes) > \
+        max(s.args["active"] for s in decodes)
+    assert all(s.args["live_pages"] < s.args["table_pages"]
+               for s in decodes)
+
+
 def test_engine_programs_carry_stable_names(traced):
-    _, _, modules = traced
+    _, _, modules, _ = traced
     assert {"jit_engine_decode", "jit_engine_prefill"} <= modules
 
 
 def test_engine_spans_change_no_token(served, traced):
     model, params = served
-    eng, _, _ = traced
+    eng, *_ = traced
     plain = _engine(model, params).run(_requests())
     assert {r: v.tokens for r, v in plain.items()} == \
         {r: v.tokens for r, v in eng.results.items()}

@@ -33,11 +33,12 @@ SITE_TAGS = (QP.TAG_ATTN_QK, QP.TAG_ATTN_AV, QP.TAG_ATTN_OUT)
 
 
 # ---------------------------------------------------------------- kernel ----
-def _paged_fixture(tables):
+def _paged_fixture(tables, KV=2):
     """A contiguous rounded cache + the same content scattered into pages
     at the placement given by ``tables``."""
-    B, KV, G, dk = 2, 2, 2, 16
-    page, n_max, P = 8, 3, 8
+    B, n_max = tables.shape
+    G, dk, page = 2, 16, 8
+    P = max(8, int(tables.max()) + 1)
     smax = page * n_max
     kq = jax.random.fold_in(KEY, 7)
     grid = parse_spec("e4m3-rn")       # lossless under e4m3 packing
@@ -66,30 +67,117 @@ TABLES_A = np.array([[3, 1, 5], [2, 6, 4]], np.int32)
 TABLES_B = np.array([[7, 2, 1], [5, 3, 6]], np.int32)
 
 
-def test_paged_decode_matches_contiguous_bitwise():
-    q, kf, vf, k_pages, v_pages, seeds, d = _paged_fixture(TABLES_A)
-    B, KV, page = d["B"], d["KV"], d["page"]
-    kw = dict(scale=0.3, window=0)
+# The lengths the kernel's walk branches on (page 8, 3 pages a request):
+# empty, one token, exactly one page, a page plus one, the full table; a
+# window shorter than the longest, whose first page then holds no valid
+# position.
+EDGE_LENGTHS = (0, 1, 8, 9, 24)
+TABLES_EDGE = np.array([[4, 11, 2], [9, 1, 14], [6, 15, 3], [12, 7, 10],
+                        [5, 13, 8]], np.int32)
+WINDOW = 10
+PAGED_CASES = [(2, tuple(LENGTHS), 0, None)] + [
+    (kv, EDGE_LENGTHS, window, fmt) for kv in (1, 5)
+    for window in (0, WINDOW) for fmt in (None, "e4m3")]
 
-    @jax.jit
+
+def _live(length, page, n_max, window):
+    """Logical pages holding a position the decode mask admits."""
+    return [j for j in range(n_max)
+            if any(k < length and (not window or k > length - 1 - window)
+                   for k in range(j * page, (j + 1) * page))]
+
+
+@pytest.mark.parametrize(
+    "n_kv,lengths,window,kv_fmt", PAGED_CASES,
+    ids=[f"kv{kv}-len{'.'.join(map(str, n))}-w{w}-{f or 'f32'}"
+         for kv, n, w, f in PAGED_CASES])
+def test_paged_decode_matches_contiguous_bitwise(n_kv, lengths, window,
+                                                 kv_fmt):
+    tables = TABLES_A if len(lengths) == 2 else TABLES_EDGE
+    q, kf, vf, k_pages, v_pages, seeds, d = _paged_fixture(tables, n_kv)
+    B, page = d["B"], d["page"]
+    lens_np = np.asarray(lengths, np.int32)
+    kw = dict(scale=0.3, window=window, kv_fmt=kv_fmt)
+
+    def pack(x):
+        return x if kv_fmt is None else common.pack_block(x, kv_fmt)
+
     def run():
-        lens, tbl = jnp.asarray(LENGTHS), jnp.asarray(TABLES_A)
-        o_paged = FA.flash_decode_paged_p(q, k_pages, v_pages, seeds, lens,
-                                          tbl, SPECS, n_kv=KV, **kw)
-        o_ref = FA.flash_decode_paged_reference(q, k_pages, v_pages, seeds,
-                                                lens, tbl, SPECS, n_kv=KV,
-                                                **kw)
+        lens, tbl = jnp.asarray(lens_np), jnp.asarray(tables)
+        kp, vp = pack(k_pages), pack(v_pages)
+        o_paged = FA.flash_decode_paged_p(q, kp, vp, seeds, lens, tbl,
+                                          SPECS, n_kv=n_kv, **kw)
+        o_ref = FA.flash_decode_paged_reference(q, kp, vp, seeds, lens, tbl,
+                                                SPECS, n_kv=n_kv, **kw)
         outs = []
         for b in range(B):     # contiguous kernel: one scalar length each
-            sl = slice(b * KV, (b + 1) * KV)
-            outs.append(FA.flash_decode_p(q[sl], kf[sl], vf[sl], seeds[sl],
-                                          LENGTHS[b], SPECS, kv_block=page,
+            sl = slice(b * n_kv, (b + 1) * n_kv)
+            outs.append(FA.flash_decode_p(q[sl], pack(kf[sl]),
+                                          pack(vf[sl]), seeds[sl],
+                                          lens_np[b], SPECS, kv_block=page,
                                           **kw))
         return o_paged, o_ref, jnp.concatenate(outs)
 
     o_paged, o_ref, o_contig = run()
     assert bool(jnp.all(o_paged == o_ref))
     assert bool(jnp.all(o_paged == o_contig))
+
+
+@pytest.mark.parametrize("window", [0, WINDOW])
+@pytest.mark.parametrize("kv_fmt", [None, "e4m3"])
+def test_paged_decode_never_reads_dead_pages(window, kv_fmt):
+    """Every table entry has a physical page of its own; those outside
+    the requests' live ranges (past the length, wholly before the window,
+    all of an empty request's) hold poison: NaN, or for packed pages
+    e4m3's largest codes (it has no NaN).  The output equals the call
+    with those pages zeroed, bit for bit.  A read of NaN in a page before
+    the window would turn the output NaN; elsewhere a dead value is
+    masked either way."""
+    n_kv, page, n_max = 2, 8, 3
+    tables = TABLES_EDGE
+    q, _, _, k_pages, v_pages, seeds, _ = _paged_fixture(tables, n_kv)
+    if kv_fmt is not None:
+        k_pages = common.pack_block(k_pages, kv_fmt)
+        v_pages = common.pack_block(v_pages, kv_fmt)
+    dead = np.ones(k_pages.shape[0], bool)
+    for b, n in enumerate(EDGE_LENGTHS):
+        for j in _live(n, page, n_max, window):
+            dead[tables[b, j] * n_kv:(tables[b, j] + 1) * n_kv] = False
+    assert dead.sum() > 2 * n_kv
+    poison = (jnp.nan, jnp.nan) if kv_fmt is None else (0x7F, 0xFF)
+    kw = dict(scale=0.3, n_kv=n_kv, window=window, kv_fmt=kv_fmt)
+    lens, tbl = jnp.asarray(EDGE_LENGTHS, jnp.int32), jnp.asarray(tables)
+
+    def run(kval, vval):
+        rows = jnp.asarray(dead)[:, None, None]
+        kp = jnp.where(rows, jnp.asarray(kval, k_pages.dtype), k_pages)
+        vp = jnp.where(rows, jnp.asarray(vval, v_pages.dtype), v_pages)
+        return FA.flash_decode_paged_p(q, kp, vp, seeds, lens, tbl, SPECS,
+                                       **kw)
+
+    o_poison, o_zero = run(*poison), run(0, 0)
+    assert bool(jnp.all(jnp.isfinite(o_poison)))
+    assert bool(jnp.all(o_poison == o_zero))
+
+
+@pytest.mark.parametrize("window", [0, WINDOW])
+def test_paged_kv_block_clamps_to_live_pages(window):
+    page, n_max = 8, 3
+    lengths = EDGE_LENGTHS + (17,)
+    tables = np.arange(1, len(lengths) * n_max + 1,
+                       dtype=np.int32).reshape(-1, n_max)
+    lens, tbl = jnp.asarray(lengths, jnp.int32), jnp.asarray(tables)
+    for b, n in enumerate(lengths):
+        live = _live(n, page, n_max, window) or [0]
+        blocks = [int(FA.paged_kv_block(b, j, lens, tbl, page=page,
+                                        window=window))
+                  for j in range(n_max)]
+        assert blocks == [tables[b, min(max(j, live[0]), live[-1])]
+                          for j in range(n_max)]
+        # the pipeline copies a block when its index changes: one copy a
+        # live page (one for an empty request, whose block is not used)
+        assert 1 + sum(x != y for x, y in zip(blocks, blocks[1:])) == \
+            len(live)
 
 
 def test_paged_decode_packed_and_placement_invariant():

@@ -108,14 +108,19 @@ def test_flash_bwd_compiles(chip, kernel):
             *a, SR8, SR8, SR8, **ATTN_KW), *ops, ((BH, 6), U32))
 
 
-def test_flash_decode_paged_packed_compiles(chip):
-    # 8 requests of up to 1056 tokens in 64-token pages of e4m3 codes
-    b, kv, g, page, n_max, pages = 8, 5, 3, 64, 17, 8 * 17 + 1
+@pytest.mark.parametrize("b,kv,g,hd,page,n_max,window", [
+    (32, 5, 3, 64, 64, 32, 0),          # the chat cell: smollm-360m
+    (8, 10, 4, 128, 64, 64, 2047),      # Phi-3-medium's heads and window
+])
+def test_flash_decode_paged_packed_compiles(chip, b, kv, g, hd, page,
+                                            n_max, window):
+    # up to n_max pages of e4m3 codes a request, the scratch page besides
+    pages = b * n_max + 1
     _compile(chip, lambda q, kp, vp, s, ln, tb: FA.flash_decode_paged_p(
-        q, kp, vp, s, ln, tb, ATTN, scale=HD ** -0.5, n_kv=kv,
-        kv_fmt="e4m3", interpret=False),
-        ((b * kv, g, HD), F32), ((pages * kv, page, HD), jnp.uint8),
-        ((pages * kv, page, HD), jnp.uint8), ((b * kv, 6), U32),
+        q, kp, vp, s, ln, tb, ATTN, scale=hd ** -0.5, n_kv=kv,
+        window=window, kv_fmt="e4m3", interpret=False),
+        ((b * kv, g, hd), F32), ((pages * kv, page, hd), jnp.uint8),
+        ((pages * kv, page, hd), jnp.uint8), ((b * kv, 6), U32),
         ((b,), I32), ((b, n_max), I32))
 
 
