@@ -38,7 +38,7 @@ import numpy as np
 
 from bench import harness
 from bench.drivers.train import model_config
-from bench.reference import dense_llama as ref
+from bench.run import load_reference
 
 
 def _lognormal_sizes(rng, spec: dict, n: int) -> np.ndarray:
@@ -100,7 +100,7 @@ def make_policy(traffic: dict):
 def serve_weights(cfg: dict, seed: int, dtype: str):
     import jax
     import jax.numpy as jnp
-    params = ref.init_params(cfg, seed)
+    params = load_reference(cfg).init_params(cfg, seed)
     return jax.jit(lambda p: jax.tree.map(
         lambda w: w.astype(jnp.dtype(dtype)), p), donate_argnums=0)(params)
 
@@ -209,7 +209,8 @@ def run(cell: dict, cfg: dict, traffic: dict, seed: int, seconds: float,
                       prefill_chunk=e["prefill_chunk"],
                       token_budget=e["token_budget"],
                       max_queue=e["max_queue"])
-    model = build_model(model_config(cfg, make_policy(traffic)))
+    model = build_model(model_config(cfg, load_reference(cfg),
+                                     make_policy(traffic)))
     params = serve_weights(cfg, seed, traffic["weights_dtype"])
     horizon = seconds + traffic["drain_seconds"]
     load = offered_load(traffic, seed, cfg["vocab_size"], seconds, horizon)
@@ -321,6 +322,7 @@ def served_gap(cfg, traffic, sample, results, params, control=None):
         return None
     length = traffic["engine"]["max_pages_per_request"] * \
         traffic["engine"]["page_size"]
+    ref = load_reference(cfg)
     worst = 0.0
     for r in sample:
         g = ref.served_token_gaps(cfg, params, r.a.prompt,

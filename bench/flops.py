@@ -1,6 +1,7 @@
 """Operations and bytes, computed from shapes.
 
-Per kernel call (for roofline shares) and per model token (for MFU).  A
+Per kernel call (for roofline shares) and per model token (for MFU; a
+family's reference module reads the sizes from its configuration).  A
 roofline share divides the least time these imply by measured device
 time, so every count here is of work the algorithm needs, never more:
 causal attention counts only the query-key pairs a causal mask keeps,
@@ -9,19 +10,7 @@ and bytes are each operand read once and each result written once.
 from __future__ import annotations
 
 
-
 # ---------------------------------------------------------------- models --
-def matmul_params(cfg) -> int:
-    """Weights that take part in a matrix product per token: the
-    projections of every layer and the output head (an embedding lookup
-    is no product)."""
-    d, hd = cfg["hidden_size"], cfg["head_dim"]
-    nh, nkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    f = cfg["intermediate_size"]
-    per_layer = d * nh * hd + 2 * d * nkv * hd + nh * hd * d + 3 * d * f
-    return cfg["num_hidden_layers"] * per_layer + d * cfg["vocab_size"]
-
-
 def causal_pairs(sq: int, skv: int, q_offset: int = 0,
                  window: int = 0) -> int:
     """Query-key pairs with key position <= query position (and, with a
@@ -35,14 +24,18 @@ def causal_pairs(sq: int, skv: int, q_offset: int = 0,
     return total
 
 
-def train_flops_per_token(cfg, seq: int) -> float:
+def train_flops_per_token(matmul_params: int, n_layers: int,
+                          qk_width: int, v_width: int, seq: int,
+                          window: int = 0) -> float:
     """Forward and backward model FLOPs per trained token, recomputation
-    not counted: 6 per matmul weight, plus causal attention's QK^T and PV
-    (2 FLOPs per multiply-add, each over head_dim, three passes)."""
-    nh, hd = cfg["num_attention_heads"], cfg["head_dim"]
-    mean_ctx = causal_pairs(seq, seq, 0, cfg["sliding_window"]) / seq
-    attn = 3 * 2 * 2 * nh * hd * mean_ctx * cfg["num_hidden_layers"]
-    return 6.0 * matmul_params(cfg) + attn
+    not counted: 6 per weight that takes part in a matrix product, plus
+    causal attention's QK^T and PV (2 FLOPs per multiply-add, three
+    passes), whose widths summed over a layer's query heads are
+    ``qk_width`` and ``v_width``.  A family's reference module says how
+    many weights and how wide (``train_flops_per_token(cfg, seq)``)."""
+    mean_ctx = causal_pairs(seq, seq, 0, window) / seq
+    attn = 3 * 2 * (qk_width + v_width) * mean_ctx * n_layers
+    return 6.0 * matmul_params + attn
 
 
 # --------------------------------------------------------------- kernels --

@@ -1,8 +1,10 @@
 """Shared look-ups for the metric readers: the kernel calls named in the
-reduced trace, made once per run."""
+reduced trace, made once per run, and the configuration's reference
+module, which counts a model family's work."""
 from __future__ import annotations
 
 from bench import kernels
+from bench.run import load_reference
 
 
 def calls(inputs: dict):
@@ -33,9 +35,12 @@ def traced_steps(inputs: dict):
             if s[0] >= tr["start"] and s[1] <= tr["stop"]]
 
 
+def reference(inputs: dict):
+    """The plain reference module of the run's configuration."""
+    return load_reference(inputs["cfg"])
+
+
 def kv_bytes_per_token(inputs: dict) -> float:
-    """Bytes of one cached token over all layers: packed 8-bit keys and
-    values of every kv head (the e4m3 cache stores one byte a value)."""
-    cfg = inputs["cfg"]
-    return (2.0 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
-            * cfg["head_dim"])
+    """Bytes of one cached token over all layers: the packed e4m3 cache
+    stores one byte a value."""
+    return float(reference(inputs).cached_values_per_token(inputs["cfg"]))

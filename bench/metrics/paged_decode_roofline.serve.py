@@ -17,13 +17,12 @@ def read(inputs):
                 if ev.name in calls and calls[ev.name].kind == "paged_decode")
     if spent <= 0:
         return None
-    cfg, peaks = inputs["cfg"], inputs["peaks"]
     per_tok = _common.kv_bytes_per_token(inputs)
-    width = 2.0 * cfg["num_attention_heads"] * cfg["head_dim"] * 2
+    attn = _common.reference(inputs).attn_flops_per_cached_token(
+        inputs["cfg"])
     least = 0.0
     for _, _, decoded, kv_live, _ in _common.traced_steps(inputs):
         if decoded:
-            least += flops.least_time(
-                width * kv_live * cfg["num_hidden_layers"],
-                per_tok * kv_live, peaks)
+            least += flops.least_time(attn * kv_live, per_tok * kv_live,
+                                      inputs["peaks"])
     return 100.0 * least / spent

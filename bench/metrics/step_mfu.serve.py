@@ -16,11 +16,11 @@ def read(inputs):
     if not _common.is_serve(inputs) or red is None or red.busy_s <= 0:
         return None
     cfg, peaks, job = inputs["cfg"], inputs["peaks"], inputs["job"]
-    n_mm = flops.matmul_params(cfg)
+    ref = _common.reference(inputs)
+    n_mm = ref.matmul_params(cfg)
     w_bytes = 2.0 * n_mm
     per_tok = _common.kv_bytes_per_token(inputs)
-    attn = 2.0 * cfg["num_attention_heads"] * cfg["head_dim"] * 2 \
-        * cfg["num_hidden_layers"]
+    attn = ref.attn_flops_per_cached_token(cfg)
     chunk = job["engine"]["prefill_chunk"]
     least = 0.0
     for _, _, decoded, kv_live, prefilled in _common.traced_steps(inputs):

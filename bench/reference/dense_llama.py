@@ -26,11 +26,53 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import flops
+from bench.reference.common import (config_items, follow_steps, product,
+                                    seed_key)
 
-def seed_key(seed: int):
-    """A PRNG key from any non-negative whole number (also above 2**32)."""
-    key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
-    return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
+# configuration keys -> the program's ModelConfig fields
+FIELDS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
+          "num_attention_heads": "n_heads",
+          "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
+          "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+          "tie_word_embeddings": "tie_embeddings",
+          "rope_theta": "rope_theta", "sliding_window": "sliding_window"}
+
+
+# ------------------------------------------------------------------ work --
+def matmul_params(cfg) -> int:
+    """Weights that take part in a matrix product per token: the
+    projections of every layer and the output head (an embedding lookup
+    is no product)."""
+    d, hd = cfg["hidden_size"], cfg["head_dim"]
+    nh, nkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    f = cfg["intermediate_size"]
+    per_layer = d * nh * hd + 2 * d * nkv * hd + nh * hd * d + 3 * d * f
+    return cfg["num_hidden_layers"] * per_layer + d * cfg["vocab_size"]
+
+
+def train_flops_per_token(cfg, seq: int) -> float:
+    """Forward and backward model FLOPs per trained token
+    (``flops.train_flops_per_token``): every head's query-key and
+    probability-value products are ``head_dim`` wide."""
+    width = cfg["num_attention_heads"] * cfg["head_dim"]
+    return flops.train_flops_per_token(
+        matmul_params(cfg), cfg["num_hidden_layers"], width, width, seq,
+        cfg["sliding_window"])
+
+
+def cached_values_per_token(cfg) -> int:
+    """Values the KV cache holds for one token over all layers: a key and
+    a value of ``head_dim`` for every kv head."""
+    return (2 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
+            * cfg["head_dim"])
+
+
+def attn_flops_per_cached_token(cfg) -> float:
+    """FLOPs one decoded token spends on one cached token over all
+    layers: QK^T and PV of every query head, 2 FLOPs a multiply-add."""
+    return 2.0 * cfg["num_attention_heads"] * cfg["head_dim"] * 2 \
+        * cfg["num_hidden_layers"]
 
 
 # ------------------------------------------------------------ parameters --
@@ -85,15 +127,6 @@ def init_params(cfg, seed: int):
 
 
 # ---------------------------------------------------------------- forward --
-def _ste(quant):
-    """``quant`` in the forward pass, identity in the backward pass."""
-    @jax.custom_vjp
-    def q(x):
-        return quant(x)
-    q.defvjp(lambda x: (quant(x), None), lambda _, g: (g,))
-    return q
-
-
 def _rms_norm(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * (1.0 + scale)
@@ -165,34 +198,7 @@ def sequence_loss(cfg, params, tokens, labels, mm=None):
     return jnp.mean(logz - gold)
 
 
-# --------------------------------------------------- low-precision control --
-def int4_absmax(x):
-    """Per-tensor symmetric int4: 15 levels, scale from the largest |x|."""
-    s = jnp.max(jnp.abs(x)) / 7.0
-    s = jnp.where(s > 0, s, 1.0)
-    return jnp.clip(jnp.round(x / s), -7, 7) * s
-
-
-def fp8_e4m3(x):
-    """Round to the OCP FP8 E4M3 grid (nearest, saturating at 448)."""
-    return jnp.clip(x, -448.0, 448.0).astype(jnp.float8_e4m3fn).astype(
-        jnp.float32)
-
-
-CONTROLS = {"int4": int4_absmax, "fp8": fp8_e4m3}
-
-
-def product(control=None):
-    """A matrix product ``mm(spec, a, b)``: plain float32 for the
-    reference; for its control, computed in the named lower precision:
-    both operands and the result rounded to that grid (forward, with a
-    straight-through backward)."""
-    if control is None:
-        return jnp.einsum
-    q = _ste(CONTROLS[control])
-    return lambda spec, a, b: q(jnp.einsum(spec, q(a), q(b)))
-
-
+# ------------------------------------------------------------ serving --
 @functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
 def _token_gaps(params, seq, nxt, cfg_items, control):
     cfg = dict(cfg_items)
@@ -204,12 +210,6 @@ def _token_gaps(params, seq, nxt, cfg_items, control):
         nxt = jnp.argmax(low, axis=-1)
     picked = jnp.take_along_axis(ref, nxt[:, None], axis=-1)[:, 0]
     return jnp.max(ref, axis=-1) - picked
-
-
-def config_items(cfg):
-    """The numeric keys of a configuration, hashable for ``jax.jit``."""
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, bool))))
 
 
 def served_token_gaps(cfg, params, prompt, served, length, control=None):
@@ -233,57 +233,7 @@ def served_token_gaps(cfg, params, prompt, served, length, control=None):
     return gaps[lo:lo + len(served)]
 
 
-# ------------------------------------------------------------ the update --
-def _magnitude_split(z):
-    """|z| = floor + frac * ulp on the bfloat16 grid (exact in float32)."""
-    bits = jax.lax.bitcast_convert_type(jnp.abs(z), jnp.uint32)
-    lo_bits = bits & jnp.uint32(0xFFFF0000)
-    lo = jax.lax.bitcast_convert_type(lo_bits, jnp.float32)
-    hi = jax.lax.bitcast_convert_type(lo_bits + jnp.uint32(0x10000),
-                                      jnp.float32)
-    frac = (bits & jnp.uint32(0xFFFF)).astype(jnp.float32) / 65536.0
-    return lo, hi, frac
-
-
-def round_bf16(z, key=None, *, eps=0.0, v=None):
-    """Round float32 ``z`` onto the bfloat16 grid.
-
-    ``key=None``: nearest (ties to even).  Otherwise stochastic: away from
-    zero with probability ``frac`` (SR), or ``clip(frac - sign(z) sign(v)
-    eps)`` (the paper's signed-SR-eps) when ``v`` is given.
-    """
-    if key is None:
-        return z.astype(jnp.bfloat16).astype(jnp.float32)
-    lo, hi, frac = _magnitude_split(z)
-    p = frac if v is None else jnp.clip(
-        frac - jnp.sign(z) * jnp.sign(v) * eps, 0.0, 1.0)
-    p = jnp.where(frac == 0.0, 0.0, p)
-    up = jax.random.uniform(key, z.shape) < p
-    return jnp.sign(z) * jnp.where(up, hi, lo)
-
-
-def sgd_update(params, mom, grads, key, *, lr, momentum, eps):
-    """SGD with momentum and the paper's rounded parameter update on the
-    bfloat16 grid (eq. 8): the effective gradient rounded to nearest, the
-    step ``lr * g`` stochastically rounded, the subtraction rounded with
-    signed-SR-eps biased against the gradient's sign.  Momentum is kept
-    in float32."""
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(key, 2 * len(leaves))
-    mom = jax.tree.map(lambda m, g: momentum * m + g, mom, grads)
-    new = []
-    for i, (x, m) in enumerate(zip(leaves, jax.tree_util.tree_leaves(mom))):
-        g_hat = round_bf16(m)
-        upd = round_bf16(lr * g_hat, keys[2 * i])
-        new.append(round_bf16(x - upd, keys[2 * i + 1], eps=eps, v=g_hat))
-    return jax.tree_util.tree_unflatten(treedef, new), mom
-
-
-def leaf_norms(tree):
-    return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-                      for x in jax.tree_util.tree_leaves(tree)])
-
-
+# ------------------------------------------------------------ training --
 @functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
 def _loss_and_grad(params, tokens, labels, cfg_items, control):
     """Batch-mean loss and its float32 gradient, one sequence at a time."""
@@ -310,21 +260,7 @@ def train_steps(cfg, job, params, batches, seed, control=None):
     loss of each step, the per-leaf norms of the first gradient, and the
     per-leaf norms of the parameters' change."""
     cfg_items = config_items(cfg)
-    mom = jax.tree.map(jnp.zeros_like, params)
-    start = params
-    losses, g1 = [], None
-    key = jax.random.fold_in(seed_key(seed), 0x5EF)
-    update = jax.jit(functools.partial(
-        sgd_update, lr=job["lr"], momentum=job["momentum"], eps=job["eps"]))
-    with jax.default_matmul_precision("highest"):
-        for i, b in enumerate(batches):
-            loss, g = _loss_and_grad(params, b["tokens"], b["labels"],
-                                     cfg_items, control)
-            losses.append(float(loss))
-            if g1 is None:
-                g1 = np.asarray(leaf_norms(g))
-            params, mom = update(params, mom, g, jax.random.fold_in(key, i))
-            del g
-        change = np.asarray(leaf_norms(
-            jax.tree.map(jnp.subtract, params, start)))
-    return {"losses": losses, "grad_norms": g1, "change_norms": change}
+    return follow_steps(
+        lambda p, b: _loss_and_grad(p, b["tokens"], b["labels"], cfg_items,
+                                    control),
+        job, params, batches, seed)

@@ -6,11 +6,13 @@
 Everything about a cell is data: ``BENCHMARK.json`` names its
 configuration (``bench/configs/<config>.json``) and its traffic mix or
 training job (``bench/traffic/<traffic>.json``, whose ``driver`` names
-``bench/drivers/<driver>.py``).  A per-layer metric is a reader of its own,
-``bench/metrics/<metric>.py``.  A run loads, warms up every shape it uses
-(set-up), measures for ``--seconds`` (the window; ``--trace 1`` traces a
-short window instead and reports the per-layer metrics), then checks what
-the timed path produced against the plain reference.
+``bench/drivers/<driver>.py``); the configuration's ``reference`` names
+its model family's plain reference, ``bench/reference/<reference>.py``.
+A per-layer metric is a reader of its own, ``bench/metrics/<metric>.py``.
+A run loads, warms up every shape it uses (set-up), measures for
+``--seconds`` (the window; ``--trace 1`` traces a short window instead
+and reports the per-layer metrics), then checks what the timed path
+produced against the plain reference.
 
 The last line of standard output is one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` when
@@ -32,6 +34,7 @@ from pathlib import Path
 T_START = time.perf_counter()
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+REFERENCES = BENCH / "reference"
 
 
 def load_module(path: Path):
@@ -45,6 +48,13 @@ def load_module(path: Path):
     sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reference(cfg: dict):
+    """The plain reference of a configuration's model family,
+    ``bench/reference/<cfg["reference"]>.py`` (``reference/common.py``
+    says what such a module gives)."""
+    return load_module(REFERENCES / f"{cfg['reference']}.py")
 
 
 def load_cell(name: str):

@@ -15,14 +15,12 @@ import pytest
 from bench import control, harness
 from bench.drivers import serve as serve_drv, train as train_drv
 from bench.tests import tiny
-
-PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
-         "hbm_bytes": 16e9}
+from bench.tests.faults import half_batch, state_unchanged, wrap
 
 
 @pytest.fixture(autouse=True)
 def no_chip(monkeypatch):
-    monkeypatch.setattr(harness, "load_peaks", lambda kind: PEAKS)
+    monkeypatch.setattr(harness, "load_peaks", lambda kind: tiny.PEAKS)
 
 
 def run_train(job_name, tmp_path, **cfg_over):
@@ -34,25 +32,8 @@ def run_train(job_name, tmp_path, **cfg_over):
 
 def wrap_train_step(monkeypatch, fault):
     from repro.launch import steps as steps_lib
-    real = steps_lib.make_train_step
-
-    def make(*a, **kw):
-        step = real(*a, **kw)
-
-        def faulty(params, opt_state, carry, batch):
-            return fault(step, params, opt_state, carry, batch)
-        return faulty
-    monkeypatch.setattr(steps_lib, "make_train_step", make)
-
-
-def state_unchanged(step, params, opt_state, carry, batch):
-    _, _, carry2, m = step(params, opt_state, carry, batch)
-    return params, opt_state, carry2, m
-
-
-def half_batch(step, params, opt_state, carry, batch):
-    half = jax.tree.map(lambda x: x[: x.shape[0] // 2], batch)
-    return step(params, opt_state, carry, half)
+    monkeypatch.setattr(steps_lib, "make_train_step",
+                        wrap(steps_lib.make_train_step, fault))
 
 
 @pytest.mark.parametrize("job", ["train-b8sr", "train-xla"])
@@ -105,3 +86,37 @@ def test_serve_control_is_not_correct():
     cfg, job = tiny.config(), tiny.traffic("serve-chat")
     gap, prog = serve_drv.control_gap(cfg, job, 13)
     assert gap > job["limits"]["served_logit_gap"] >= prog, (gap, prog)
+
+
+def four_device_runs(seed, *faults):
+    """The tiny ``train-wire4`` job on four virtual CPU devices, in a
+    process of its own (``four_devices.py``); one record per run."""
+    import json
+    import subprocess
+    import sys
+    from pathlib import Path
+    here = Path(__file__).resolve().parent
+    proc = subprocess.run(
+        [sys.executable, str(here / "four_devices.py"), str(seed), *faults],
+        capture_output=True, text=True, timeout=900, cwd=here.parents[1])
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return [json.loads(line) for line in proc.stdout.splitlines()
+            if line.startswith("{")]
+
+
+def test_wire_job_on_four_devices_is_correct_and_faults_are_not():
+    runs = four_device_runs(2 ** 31 + 11, "sound", "state_unchanged",
+                            "half_batch", "no_exchange")
+    assert [r["fault"] for r in runs] == ["sound", "state_unchanged",
+                                          "half_batch", "no_exchange"]
+    sound, *faulty = runs
+    assert sound["correct"] and sound["chips"] == 4, sound
+    assert sound["attempted"] >= 3 and sound["failed"] == 0, sound
+    for r in faulty:
+        assert not r["correct"], r
+
+
+def test_wire_control_is_not_correct():
+    cfg, traffic = tiny.config(), tiny.traffic("train-wire4")
+    nums = control.variant_numbers(cfg, traffic, 11, "control", chips=4)
+    assert any(nums[k] > v for k, v in traffic["limits"].items()), nums

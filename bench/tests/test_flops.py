@@ -49,7 +49,7 @@ def test_model_flops_per_token_match_xla_on_the_matmuls():
     # the reference's forward with attention products counted over all
     # pairs: XLA counts masked pairs too, so subtract the masked part
     fwd, _ = cost(lambda p, t: ref.sequence_logits(cfg, p, t), params, toks)
-    per_tok = flops.train_flops_per_token(cfg, seq) / 3.0
+    per_tok = ref.train_flops_per_token(cfg, seq) / 3.0
     nh, hd = cfg["num_attention_heads"], cfg["head_dim"]
     masked = 2 * 2 * nh * hd * (seq * seq - flops.causal_pairs(seq, seq))
     masked *= cfg["num_hidden_layers"]
